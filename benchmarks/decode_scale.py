@@ -119,8 +119,11 @@ def run(quick: bool = False) -> dict:
 
     rng = np.random.default_rng(11)
     bs, P, n_phys, KV, hd = 16, 4, 12, cfg.num_kv_heads, cfg.head_dim
-    kp = jnp.asarray(rng.standard_normal((n_phys, bs, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_phys, bs, KV, hd)), jnp.float32)
+    # drawn (block, offset, head)-ordered, stored kv-head-major
+    kp = jnp.asarray(rng.standard_normal((n_phys, bs, KV, hd)).swapaxes(1, 2),
+                     jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((n_phys, bs, KV, hd)).swapaxes(1, 2),
+                     jnp.float32)
     q = jnp.asarray(rng.standard_normal((2, cfg.num_heads, hd)), jnp.float32)
     tbl = jnp.asarray(np.stack([rng.permutation(np.arange(2, n_phys))[:P]
                                 for _ in range(2)]).astype(np.int32))

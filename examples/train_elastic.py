@@ -39,6 +39,26 @@ PRESETS = {
 }
 
 
+def build_trainer(preset, ckpt_dir, *, devices, model_par=2, steps=None,
+                  log=print):
+    """The preset's model, optimizer, synthetic data and checkpointer behind
+    an ElasticTrainer on ``devices``. ``steps`` sets the length of the
+    learning-rate schedule (default: the preset's)."""
+    p = PRESETS[preset]
+    cfg = ModelConfig(
+        name=f"llama-{preset}", family="dense",
+        num_layers=p["num_layers"], d_model=p["d_model"],
+        num_heads=p["num_heads"], num_kv_heads=p["num_kv_heads"],
+        head_dim=p["head_dim"], d_ff=p["d_ff"], vocab_size=p["vocab_size"],
+        dtype="float32", param_dtype="float32", remat="none",
+        num_microbatches=2, attn_chunk_q=128, attn_chunk_k=128)
+    model = build_model(cfg)
+    opt = AdamW(lr=cosine_schedule(3e-3, 20, steps or p["steps"]))
+    data = SyntheticBatches(cfg, global_batch=p["batch"], seq_len=p["seq"])
+    return ElasticTrainer(model, opt, data, Checkpointer(ckpt_dir, keep=3),
+                          model_par=model_par, devices=devices, log=log)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="tiny", choices=sorted(PRESETS))
@@ -46,23 +66,10 @@ def main():
     args = ap.parse_args()
     p = PRESETS[args.preset]
 
-    cfg = ModelConfig(
-        name=f"llama-{args.preset}", family="dense",
-        num_layers=p["num_layers"], d_model=p["d_model"],
-        num_heads=p["num_heads"], num_kv_heads=p["num_kv_heads"],
-        head_dim=p["head_dim"], d_ff=p["d_ff"], vocab_size=p["vocab_size"],
-        dtype="float32", param_dtype="float32", remat="none",
-        num_microbatches=2, attn_chunk_q=128, attn_chunk_k=128)
-    model = build_model(cfg)
-    print(f"model: {model.param_count()/1e6:.1f}M params; "
-          f"devices: {len(jax.devices())}")
-
-    opt = AdamW(lr=cosine_schedule(3e-3, 20, p["steps"]))
-    data = SyntheticBatches(cfg, global_batch=p["batch"], seq_len=p["seq"])
     ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="coaster_ckpt_")
-    trainer = ElasticTrainer(model, opt, data, Checkpointer(ckpt_dir, keep=3),
-                             model_par=2, devices=jax.devices()[:8],
-                             log=print)
+    trainer = build_trainer(args.preset, ckpt_dir, devices=jax.devices()[:8])
+    print(f"model: {trainer.model.param_count()/1e6:.1f}M params; "
+          f"devices: {len(jax.devices())}")
     print(f"training {p['steps']} steps; simulated revocation of one pod "
           f"(8 -> 4 devices) at step {p['preempt_step']}")
     trainer.run(p["steps"], preempt_at={p["preempt_step"]: 4},

@@ -43,6 +43,7 @@ SKIP_POLICY: List[Tuple[str, str]] = [
     (r"-m\s+repro\.launch\.smoke", "scenario catalog has its own CI job"),
     (r"-m\s+repro\.analysis\.sanitize", "sanitizer runs in scenario-smoke"),
     (r"serve_multitenant|serve_bursty", "minutes of real model decode"),
+    (r"chip_smoke\.py", "needs a TPU; CI has none"),
 ]
 
 

@@ -135,6 +135,11 @@ def resolve_overrides(**named) -> Tuple[Dict, Dict]:
 EngineAdapter = Callable[..., RunResult]
 _ENGINES: Dict[str, EngineAdapter] = {}
 
+#: engines whose runs never touch JAX and so may run in pool workers. A JAX
+#: child of a parent that holds the chip would fail or hang on it, so every
+#: other engine runs in the calling process.
+POOL_SAFE_ENGINES = ("des",)
+
 
 def register_engine(name: str, adapter: EngineAdapter, *,
                     overwrite: bool = False) -> EngineAdapter:
@@ -460,7 +465,8 @@ def sweep(scenario: Union[str, Scenario], grid: Dict[str, Sequence],
       pointwise fan-out below.
     * ``engine="des"`` (or any registered adapter): Cartesian fan-out, one
       full engine run per point — serial, or multiprocess with
-      ``processes=N``.  Axis names are ``OVERRIDE_SPEC`` aliases (``r``,
+      ``processes=N`` for the engines in ``POOL_SAFE_ENGINES`` (JAX
+      engines always run in this process).  Axis names are ``OVERRIDE_SPEC`` aliases (``r``,
       ``p``, ``threshold``...) or raw ``SimConfig`` fields.  Result dims
       follow ``grid`` insertion order.
     """
@@ -637,6 +643,8 @@ def _sweep_pointwise(sc: Scenario, grid: Dict[str, Sequence], engine: str, *,
     adapter = _get_engine(engine)
     points = [(sc, adapter, dict(zip(grid, combo)), common)
               for combo in itertools.product(*grid.values())]
+    if engine not in POOL_SAFE_ENGINES:
+        processes = 1
     if processes and processes > 1:
         from concurrent.futures import ProcessPoolExecutor
 

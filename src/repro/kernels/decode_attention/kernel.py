@@ -12,7 +12,7 @@ Grid: (B, KV, n_L_blocks). All G=H/KV query heads of a kv-head ride in one
 block (G x hd fits VMEM), so the MXU sees (G, hd) x (hd, bL) matmuls.
 
 Paged variant (``paged_decode_attention_fwd``): the cache is a shared pool of
-fixed-size blocks (``k_pages``/``v_pages``: (n_phys_blocks, block_size, KV,
+fixed-size blocks (``k_pages``/``v_pages``: (n_phys_blocks, KV, block_size,
 hd)) and each sequence's logical page ``j`` resolves to a physical block
 through a per-sequence ``page_table`` row. The table rides in as a
 *scalar-prefetch* operand (``pltpu.PrefetchScalarGridSpec``), so the
@@ -30,7 +30,7 @@ Deviations / assumptions (inventory, serving_jax docstring convention):
     multiple of the lane count (128); the reference engine runs block_size
     16-32 under interpret mode on CPU, where this only costs grid steps.
   * int8 KV: when ``k_scale``/``v_scale`` are passed, K/V pools are int8
-    with per-(block, slot, kv-head) f32 scales over the hd axis
+    with per-(block, kv-head, slot) f32 scales over the hd axis
     (optim.compress.quantize_int8 rowwise layout); dequantization happens
     in-kernel after the gather, so HBM traffic stays int8. The f32 path
     and the int8 path intentionally share the softmax accumulator math.
@@ -70,7 +70,7 @@ def _kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_scr, l_scr, acc_scr, *,
                             preferred_element_type=jnp.float32)  # (G, bL)
     if softcap:
         s = softcap * jnp.tanh(s / softcap)
-    s = s + bias_ref[...][None, :]
+    s = s + bias_ref[...]  # (1, bL) broadcast over the G query heads
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -90,7 +90,10 @@ def _kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def decode_attention_fwd(q, k, v, bias, *, softcap=0.0, block_l=256,
                          interpret=False):
-    """q: (B,H,hd); k,v: (B,KV,L,hd); bias: (L,) f32. Returns (B,H,hd)."""
+    """q: (B,H,hd); k,v: (B,KV,L,hd); bias: (L,) f32. Returns (B,H,hd).
+
+    The bias rides in as a (1, L) row: a 1-D f32 block would need a tile
+    the TPU's 1-D layout (T(1024)) does not give below 1024 elements."""
     B, H, hd = q.shape
     KV, L = k.shape[1], k.shape[2]
     G = H // KV
@@ -98,6 +101,7 @@ def decode_attention_fwd(q, k, v, bias, *, softcap=0.0, block_l=256,
     assert L % bl == 0, (L, bl)
     n_l = L // bl
     qg = q.reshape(B, KV, G, hd)
+    bias = bias.reshape(1, L)
 
     kern = functools.partial(_kernel, scale=hd**-0.5, softcap=softcap, n_l=n_l)
     out = pl.pallas_call(
@@ -107,7 +111,7 @@ def decode_attention_fwd(q, k, v, bias, *, softcap=0.0, block_l=256,
             pl.BlockSpec((1, 1, G, hd), lambda b, g, j: (b, g, 0, 0)),
             pl.BlockSpec((1, 1, bl, hd), lambda b, g, j: (b, g, j, 0)),
             pl.BlockSpec((1, 1, bl, hd), lambda b, g, j: (b, g, j, 0)),
-            pl.BlockSpec((bl,), lambda b, g, j: (j,)),
+            pl.BlockSpec((1, bl), lambda b, g, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, g, j: (b, g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
@@ -142,14 +146,14 @@ def _paged_kernel(tbl_ref, q_ref, k_ref, v_ref, bias_ref, *rest, scale,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, hd)
-    k = k_ref[0, :, 0, :]  # (bs, hd) — one physical block of this kv-head
+    k = k_ref[0, 0]  # (bs, hd) — one physical block of this kv-head
     if quantized:
-        k = k.astype(jnp.float32) * ks_ref[0, :, 0, :]
+        k = k.astype(jnp.float32) * ks_ref[0, 0]
     s = jax.lax.dot_general(q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, bs)
     if softcap:
         s = softcap * jnp.tanh(s / softcap)
-    s = s + bias_ref[0][None, :]
+    s = s + bias_ref[0, 0]  # (1, bs) broadcast over the G query heads
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -157,11 +161,11 @@ def _paged_kernel(tbl_ref, q_ref, k_ref, v_ref, bias_ref, *rest, scale,
     alpha = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
     if quantized:
-        v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0, :, 0, :]
+        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
         pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     else:
-        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, :, 0, :],
+        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, 0],
                                  (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     acc_scr[...] = acc_scr[...] * alpha + pv
@@ -175,16 +179,22 @@ def _paged_kernel(tbl_ref, q_ref, k_ref, v_ref, bias_ref, *rest, scale,
 def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, bias, *,
                                k_scale=None, v_scale=None, softcap=0.0,
                                interpret=False):
-    """q: (B,H,hd); k_pages,v_pages: (n_phys,bs,KV,hd); page_table: (B,P)
+    """q: (B,H,hd); k_pages,v_pages: (n_phys,KV,bs,hd); page_table: (B,P)
     int32; bias: (B, P*bs) f32 (NEG_INF = blocked — covers causality,
     sliding windows, unwritten/NULL slots). Optional k_scale/v_scale:
-    (n_phys,bs,KV,1) f32 for int8 pools. Returns (B,H,hd)."""
+    (n_phys,KV,bs,1) f32 for int8 pools. Returns (B,H,hd).
+
+    The pool is kv-head-major so one grid step's K/V block ends in the
+    dense tile ``(bs, hd)``; on the chip ``bs`` must be a multiple of 8
+    (the f32 sublane count). The bias rides in as (B, P, 1, bs) so its
+    block's last two dimensions equal the array's."""
     B, H, hd = q.shape
-    n_phys, bs, KV, _ = k_pages.shape
+    n_phys, KV, bs, _ = k_pages.shape
     P = page_table.shape[1]
     assert bias.shape == (B, P * bs), (bias.shape, B, P, bs)
     G = H // KV
     qg = q.reshape(B, KV, G, hd)
+    bias = bias.reshape(B, P, 1, bs)
     quantized = k_scale is not None
 
     kern = functools.partial(_paged_kernel, scale=hd**-0.5, softcap=softcap,
@@ -192,15 +202,15 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, bias, *,
     # index maps receive the prefetched table ref after the grid indices
     in_specs = [
         pl.BlockSpec((1, 1, G, hd), lambda b, g, j, t: (b, g, 0, 0)),
-        pl.BlockSpec((1, bs, 1, hd), lambda b, g, j, t: (t[b, j], 0, g, 0)),
-        pl.BlockSpec((1, bs, 1, hd), lambda b, g, j, t: (t[b, j], 0, g, 0)),
-        pl.BlockSpec((1, bs), lambda b, g, j, t: (b, j)),
+        pl.BlockSpec((1, 1, bs, hd), lambda b, g, j, t: (t[b, j], g, 0, 0)),
+        pl.BlockSpec((1, 1, bs, hd), lambda b, g, j, t: (t[b, j], g, 0, 0)),
+        pl.BlockSpec((1, 1, 1, bs), lambda b, g, j, t: (b, j, 0, 0)),
     ]
     inputs = [qg, k_pages, v_pages, bias]
     if quantized:
         in_specs += [
-            pl.BlockSpec((1, bs, 1, 1), lambda b, g, j, t: (t[b, j], 0, g, 0)),
-            pl.BlockSpec((1, bs, 1, 1), lambda b, g, j, t: (t[b, j], 0, g, 0)),
+            pl.BlockSpec((1, 1, bs, 1), lambda b, g, j, t: (t[b, j], g, 0, 0)),
+            pl.BlockSpec((1, 1, bs, 1), lambda b, g, j, t: (t[b, j], g, 0, 0)),
         ]
         inputs += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -2,18 +2,16 @@
 
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.decode_attention.kernel import (decode_attention_fwd,
                                                    paged_decode_attention_fwd)
 
 
 def decode_attention(q, k, v, bias, *, softcap=0.0, block_l=256,
                      interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return decode_attention_fwd(q, k, v, bias, softcap=softcap,
-                                block_l=block_l, interpret=interpret)
+                                block_l=block_l,
+                                interpret=interpret_mode(interpret))
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, bias, *,
@@ -22,12 +20,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, bias, *,
     """Decode attention against a paged KV pool — the gather through
     ``page_table`` happens inside the kernel (scalar-prefetch BlockSpecs).
 
-    q: (B,H,hd); k_pages/v_pages: (n_phys_blocks, block_size, KV, hd);
+    q: (B,H,hd); k_pages/v_pages: (n_phys_blocks, KV, block_size, hd);
     page_table: (B,P) int32; bias: (B, P*block_size) f32 additive mask.
-    k_scale/v_scale: (n_phys_blocks, block_size, KV, 1) f32 when the pools
+    k_scale/v_scale: (n_phys_blocks, KV, block_size, 1) f32 when the pools
     are int8 (in-kernel dequantization). Returns (B,H,hd)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return paged_decode_attention_fwd(q, k_pages, v_pages, page_table, bias,
                                       k_scale=k_scale, v_scale=v_scale,
-                                      softcap=softcap, interpret=interpret)
+                                      softcap=softcap,
+                                      interpret=interpret_mode(interpret))

@@ -37,20 +37,20 @@ def paged_decode_attention_ref(q, k_pages, v_pages, page_table, bias, *,
     same masked softmax-attention as ``decode_attention_ref`` with a
     per-sequence bias.
 
-    q: (B,H,hd); k_pages/v_pages: (n_phys, bs, KV, hd); page_table: (B,P)
-    int32; bias: (B, P*bs) f32; k_scale/v_scale: (n_phys, bs, KV, 1) f32.
+    q: (B,H,hd); k_pages/v_pages: (n_phys, KV, bs, hd); page_table: (B,P)
+    int32; bias: (B, P*bs) f32; k_scale/v_scale: (n_phys, KV, bs, 1) f32.
     """
     B, H, hd = q.shape
-    n_phys, bs, KV, _ = k_pages.shape
+    n_phys, KV, bs, _ = k_pages.shape
     P = page_table.shape[1]
     L = P * bs
-    k = k_pages[page_table]  # (B, P, bs, KV, hd)
+    k = k_pages[page_table]  # (B, P, KV, bs, hd)
     v = v_pages[page_table]
     if k_scale is not None:
         k = k.astype(jnp.float32) * k_scale[page_table]
         v = v.astype(jnp.float32) * v_scale[page_table]
-    k = k.reshape(B, L, KV, hd)
-    v = v.reshape(B, L, KV, hd)
+    k = k.swapaxes(2, 3).reshape(B, L, KV, hd)
+    v = v.swapaxes(2, 3).reshape(B, L, KV, hd)
     G = H // KV
     scale = hd**-0.5
     qg = q.reshape(B, KV, G, hd)

@@ -1,8 +1,8 @@
 """Public flash-attention op: jit'd wrapper + memory-frugal custom VJP.
 
-Forward runs the Pallas kernel (interpret=True off-TPU). Backward recomputes
-attention from (q, k, v) via the reference implementation — no O(S^2)
-probability residuals are saved, which is the kernel's training-memory win
+Forward runs the Pallas kernel (interpret mode on the CPU backend). Backward
+recomputes attention from (q, k, v) via the reference implementation — no
+O(S^2) probability residuals are saved, which is the kernel's training-memory win
 over the autodiff'd jnp path (see EXPERIMENTS.md §Perf).
 """
 
@@ -13,14 +13,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.flash_attention.ref import attention_ref
-
-
-def _use_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -30,7 +25,7 @@ def _flash(q, k, v, causal, window, softcap, prefix_len, q_offset,
     return flash_attention_fwd(
         q, k, v, causal=causal, window=window, softcap=softcap,
         prefix_len=prefix_len, q_offset=q_offset, block_q=block_q,
-        block_k=block_k, interpret=_use_interpret(interpret))
+        block_k=block_k, interpret=interpret_mode(interpret))
 
 
 def _fwd(q, k, v, causal, window, softcap, prefix_len, q_offset,

@@ -44,7 +44,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref, s_scr,
         k_t = k_ref[0, 0, t, :].astype(jnp.float32)
         v_t = v_ref[0, 0, t, :].astype(jnp.float32)
         w_t = w_ref[0, 0, t, :].astype(jnp.float32)
-        u = u_ref[0].astype(jnp.float32)
+        u = u_ref[0, 0].astype(jnp.float32)
         kv = k_t[:, None] * v_t[None, :]  # (hd_k, hd_v)
         y_t = jnp.sum(r_t[:, None] * (s + u[:, None] * kv), axis=0)
         y_ref[0, 0, t, :] = y_t.astype(y_ref.dtype)
@@ -64,8 +64,11 @@ def rwkv6_scan_fwd(r, k, v, w, u, s0, *, chunk=64, interpret=False,
 
     save_states=True additionally returns the per-chunk start states
     (B,H,n_chunks,hd,hd) — the checkpoints the backward kernel rewinds from.
+    ``u`` rides in as (H, 1, hd) so its block's last two dimensions equal
+    the array's (the TPU tiling refuses a (1, hd) block of (H, hd)).
     """
     B, H, S, hd = r.shape
+    u = u.reshape(H, 1, hd)
     c = min(chunk, S)
     assert S % c == 0, (S, c)
     n_chunks = S // c
@@ -91,7 +94,7 @@ def rwkv6_scan_fwd(r, k, v, w, u, s0, *, chunk=64, interpret=False,
         kern,
         grid=(B, H, n_chunks),
         in_specs=[seq_spec, seq_spec, seq_spec, seq_spec,
-                  pl.BlockSpec((1, hd), lambda b, h, i: (h, 0)),
+                  pl.BlockSpec((1, 1, hd), lambda b, h, i: (h, 0, 0)),
                   state_spec],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -118,7 +121,7 @@ def _bwd_kernel(r_ref, k_ref, v_ref, w_ref, dy_ref, u_ref, sstart_ref,
     def _init():
         g_scr[...] = dsT_ref[0, 0].astype(jnp.float32)
 
-    u = u_ref[0].astype(jnp.float32)
+    u = u_ref[0, 0].astype(jnp.float32)
 
     def fstep(t, s):
         hist_scr[t] = s
@@ -156,7 +159,7 @@ def _bwd_kernel(r_ref, k_ref, v_ref, w_ref, dy_ref, u_ref, sstart_ref,
     g, du = jax.lax.fori_loop(0, chunk, bstep,
                               (g_scr[...], jnp.zeros((hd,), jnp.float32)))
     g_scr[...] = g
-    du_ref[0, 0, 0, :] = du
+    du_ref[0, 0, 0, 0, :] = du
 
     @pl.when(ic == n_chunks - 1)
     def _ds0():
@@ -167,6 +170,7 @@ def rwkv6_scan_bwd(r, k, v, w, dy, u, s_starts, dsT, *, chunk=64,
                    interpret=False):
     """Returns (dr, dk, dv, dw, du_chunks (B,H,nc,hd), ds0)."""
     B, H, S, hd = r.shape
+    u = u.reshape(H, 1, hd)
     c = min(chunk, S)
     n_chunks = S // c
     rev = lambda b, h, i: (b, h, n_chunks - 1 - i, 0)
@@ -177,24 +181,25 @@ def rwkv6_scan_bwd(r, k, v, w, dy, u, s_starts, dsT, *, chunk=64,
         kern,
         grid=(B, H, n_chunks),
         in_specs=[seq_spec, seq_spec, seq_spec, seq_spec, seq_spec,
-                  pl.BlockSpec((1, hd), lambda b, h, i: (h, 0)),
+                  pl.BlockSpec((1, 1, hd), lambda b, h, i: (h, 0, 0)),
                   pl.BlockSpec((1, 1, 1, hd, hd),
                                lambda b, h, i: (b, h, n_chunks - 1 - i, 0, 0)),
                   state_spec],
         out_specs=[seq_spec, seq_spec, seq_spec, seq_spec,
-                   pl.BlockSpec((1, 1, 1, hd),
-                                lambda b, h, i: (b, h, n_chunks - 1 - i, 0)),
+                   pl.BlockSpec((1, 1, 1, 1, hd),
+                                lambda b, h, i: (b, h, n_chunks - 1 - i, 0, 0)),
                    state_spec],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, S, hd), r.dtype),
             jax.ShapeDtypeStruct((B, H, S, hd), k.dtype),
             jax.ShapeDtypeStruct((B, H, S, hd), v.dtype),
             jax.ShapeDtypeStruct((B, H, S, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, n_chunks, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, n_chunks, 1, hd), jnp.float32),
             jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[_VMEM((hd, hd), jnp.float32),
                         _VMEM((c, hd, hd), jnp.float32)],
         interpret=interpret,
     )(r, k, v, w, dy, u, s_starts, dsT)
-    return outs
+    dr, dk, dv, dw, du_chunks, ds0 = outs
+    return dr, dk, dv, dw, du_chunks[:, :, :, 0], ds0

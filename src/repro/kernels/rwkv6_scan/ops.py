@@ -16,20 +16,19 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.rwkv6_scan.kernel import (rwkv6_scan_bwd, rwkv6_scan_fwd)
 from repro.kernels.rwkv6_scan.ref import rwkv6_scan_ref
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def _wkv(r, k, v, w, u, s0, chunk, interpret, bwd_impl):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     return rwkv6_scan_fwd(r, k, v, w, u, s0, chunk=chunk, interpret=interpret)
 
 
 def _fwd(r, k, v, w, u, s0, chunk, interpret, bwd_impl):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     if bwd_impl == "ref":
         y, sT = rwkv6_scan_fwd(r, k, v, w, u, s0, chunk=chunk,
                                interpret=interpret)
@@ -45,8 +44,7 @@ def _bwd(chunk, interpret, bwd_impl, res, cts):
     if bwd_impl == "ref" or s_starts is None:
         _, vjp = jax.vjp(rwkv6_scan_ref, r, k, v, w, u, s0)
         return vjp((dy, dsT))
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     dr, dk, dv, dw, du_chunks, ds0 = rwkv6_scan_bwd(
         r, k, v, w, dy.astype(jnp.float32), u, s_starts,
         dsT.astype(jnp.float32), chunk=chunk, interpret=interpret)

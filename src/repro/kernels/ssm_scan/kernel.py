@@ -26,6 +26,9 @@ except Exception:  # pragma: no cover
     _VMEM = None
 
 
+_BWD_HIST_BYTES = 8 << 20
+
+
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref, y_ref, hT_ref,
             h_scr, *, chunk, n_chunks, hstart_ref=None):
     ic = pl.program_id(2)
@@ -38,7 +41,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref, y_ref, hT_ref,
         hstart_ref[0, 0] = h_scr[...]
 
     A = a_ref[...].astype(jnp.float32)  # (bd, N)
-    Dk = d_ref[...].astype(jnp.float32)  # (bd,)
+    Dk = d_ref[0].astype(jnp.float32)  # (bd,)
 
     def step(t, h):
         x_t = x_ref[0, t, :].astype(jnp.float32)  # (bd,)
@@ -64,8 +67,11 @@ def ssm_scan_fwd(x, dt, A, Bc, Cc, D, h0, *, chunk=64, block_d=512,
     """x, dt: (B,S,Di); Bc,Cc: (B,S,N); A: (Di,N); D: (Di,); h0: (B,Di,N).
 
     save_states=True also returns per-chunk start states
-    (B, n_chunks, Di, N) for the backward kernel."""
+    (B, n_chunks, Di, N) for the backward kernel. ``D`` rides in as a
+    (1, Di) row: a 1-D f32 block would need a tile the TPU's 1-D layout
+    (T(1024)) does not give below 1024 elements."""
     B, S, Di = x.shape
+    D = D.reshape(1, Di)
     N = A.shape[1]
     c = min(chunk, S)
     bd = min(block_d, Di)
@@ -100,7 +106,7 @@ def ssm_scan_fwd(x, dt, A, Bc, Cc, D, h0, *, chunk=64, block_d=512,
             pl.BlockSpec((bd, N), lambda b, d, i: (d, 0)),  # A
             bn_spec,  # B
             bn_spec,  # C
-            pl.BlockSpec((bd,), lambda b, d, i: (d,)),  # D
+            pl.BlockSpec((1, bd), lambda b, d, i: (0, d)),  # D
             pl.BlockSpec((1, bd, N), lambda b, d, i: (b, d, 0)),  # h0
         ],
         out_specs=out_specs,
@@ -124,7 +130,7 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, dy_ref, hstart_ref,
         g_scr[...] = dhT_ref[0].astype(jnp.float32)
 
     A = a_ref[...].astype(jnp.float32)  # (bd, N)
-    Dk = d_ref[...].astype(jnp.float32)  # (bd,)
+    Dk = d_ref[0].astype(jnp.float32)  # (bd,)
 
     def fstep(t, h):
         hist_scr[t] = h  # h_{t-1}
@@ -170,7 +176,7 @@ def _bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, dy_ref, hstart_ref,
          jnp.zeros((bd,), jnp.float32)))
     g_scr[...] = g
     da_ref[0, 0] = dA_acc
-    dd_ref[0, 0] = dD_acc
+    dd_ref[0, 0, 0] = dD_acc
 
     @pl.when(ic == n_chunks - 1)
     def _dh0():
@@ -181,9 +187,17 @@ def ssm_scan_bwd(x, dt, A, Bc, Cc, D, dy, h_starts, dhT, *, chunk=64,
                  block_d=512, interpret=False):
     """Returns (dx, ddt, dA_chunks, dB, dC, dD_chunks, dh0)."""
     B, S, Di = x.shape
+    D = D.reshape(1, Di)
     N = A.shape[1]
     c = min(chunk, S)
     bd = min(block_d, Di)
+    # The rewind history (c, bd, N) f32 sits in VMEM with N padded to the
+    # 128 lanes; halve the channel block until it fits half the TPU's
+    # 16 MiB scoped VMEM, keeping it a multiple of the 128 lanes (the
+    # forward's block_d may be larger: h_starts are blocked over channels
+    # independently).
+    while c * bd * max(N, 128) * 4 > _BWD_HIST_BYTES and bd % 256 == 0:
+        bd //= 2
     n_chunks = S // c
     n_d = Di // bd
     rev_i = lambda i: n_chunks - 1 - i
@@ -197,7 +211,7 @@ def ssm_scan_bwd(x, dt, A, Bc, Cc, D, dy, h_starts, dhT, *, chunk=64,
             xd_spec, xd_spec,
             pl.BlockSpec((bd, N), lambda b, d, i: (d, 0)),  # A
             bn_spec, bn_spec,
-            pl.BlockSpec((bd,), lambda b, d, i: (d,)),  # D
+            pl.BlockSpec((1, bd), lambda b, d, i: (0, d)),  # D
             xd_spec,  # dy
             pl.BlockSpec((1, 1, bd, N), lambda b, d, i: (b, rev_i(i), d, 0)),
             pl.BlockSpec((1, bd, N), lambda b, d, i: (b, d, 0)),  # dhT
@@ -209,7 +223,7 @@ def ssm_scan_bwd(x, dt, A, Bc, Cc, D, dy, h_starts, dhT, *, chunk=64,
             # dB/dC are per-d-block partials (summed over axis 1 in ops)
             pl.BlockSpec((1, 1, c, N), lambda b, d, i: (b, d, rev_i(i), 0)),
             pl.BlockSpec((1, 1, c, N), lambda b, d, i: (b, d, rev_i(i), 0)),
-            pl.BlockSpec((1, 1, bd), lambda b, d, i: (b, rev_i(i), d)),
+            pl.BlockSpec((1, 1, 1, bd), lambda b, d, i: (b, rev_i(i), 0, d)),
             pl.BlockSpec((1, bd, N), lambda b, d, i: (b, d, 0)),  # dh0
         ],
         out_shape=[
@@ -218,11 +232,12 @@ def ssm_scan_bwd(x, dt, A, Bc, Cc, D, dy, h_starts, dhT, *, chunk=64,
             jax.ShapeDtypeStruct((B, n_chunks, Di, N), jnp.float32),
             jax.ShapeDtypeStruct((B, n_d, S, N), jnp.float32),
             jax.ShapeDtypeStruct((B, n_d, S, N), jnp.float32),
-            jax.ShapeDtypeStruct((B, n_chunks, Di), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_chunks, 1, Di), jnp.float32),
             jax.ShapeDtypeStruct((B, Di, N), jnp.float32),
         ],
         scratch_shapes=[_VMEM((bd, N), jnp.float32),
                         _VMEM((c, bd, N), jnp.float32)],
         interpret=interpret,
     )(x, dt, A, Bc, Cc, D, dy, h_starts, dhT)
-    return outs
+    dx, ddt, dA_chunks, dB_p, dC_p, dD_chunks, dh0 = outs
+    return dx, ddt, dA_chunks, dB_p, dC_p, dD_chunks[:, :, 0], dh0

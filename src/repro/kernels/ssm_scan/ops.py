@@ -16,21 +16,20 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.ssm_scan.kernel import ssm_scan_bwd, ssm_scan_fwd
 from repro.kernels.ssm_scan.ref import ssm_scan_ref
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
 def _ssm(x, dt, A, Bc, Cc, D, h0, chunk, block_d, interpret, bwd_impl):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     return ssm_scan_fwd(x, dt, A, Bc, Cc, D, h0, chunk=chunk,
                         block_d=block_d, interpret=interpret)
 
 
 def _fwd(x, dt, A, Bc, Cc, D, h0, chunk, block_d, interpret, bwd_impl):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     if bwd_impl == "ref":
         y, hT = ssm_scan_fwd(x, dt, A, Bc, Cc, D, h0, chunk=chunk,
                              block_d=block_d, interpret=interpret)
@@ -47,8 +46,7 @@ def _bwd(chunk, block_d, interpret, bwd_impl, res, cts):
     if bwd_impl == "ref" or h_starts is None:
         _, vjp = jax.vjp(ssm_scan_ref, x, dt, A, Bc, Cc, D, h0)
         return vjp((dy, dhT))
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     dx, ddt, dA_chunks, dB_p, dC_p, dD_chunks, dh0 = ssm_scan_bwd(
         x, dt, A, Bc, Cc, D, dy.astype(jnp.float32), h_starts,
         dhT.astype(jnp.float32), chunk=chunk, block_d=block_d,

@@ -51,6 +51,9 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.scenario:
         _run_fleet(args)
         return
@@ -82,7 +85,9 @@ def main():
         prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, P)), jnp.int32)
         logits, cache = model.prefill(params, tokens=prompt, max_len=max_len, **kw)
 
-    step = jax.jit(lambda c, t, pos: model.decode_step(params, c, tokens=t, pos=pos))
+    # weights as an argument, not a closure (a closure bakes them into the
+    # program as constants)
+    step = jax.jit(lambda p, c, t, pos: model.decode_step(p, c, tokens=t, pos=pos))
     tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
     pos0 = P + (cfg.prefix_len if cfg.family == "vlm" else 0)
     t0 = time.perf_counter()
@@ -93,7 +98,7 @@ def main():
             logits, cache = model.decode_step(params, cache, embeds=emb,
                                               pos=jnp.int32(pos0 + i))
         else:
-            logits, cache = step(cache, tok, jnp.int32(pos0 + i))
+            logits, cache = step(params, cache, tok, jnp.int32(pos0 + i))
         tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
         outs.append(np.asarray(tok[:, 0]))
     dt = time.perf_counter() - t0

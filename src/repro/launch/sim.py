@@ -65,6 +65,9 @@ def main():
             print(f"{name:24s} {get_scenario(name).description}")
         return
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     sc = get_scenario(args.scenario)
     trace_over, sim_over = resolve_overrides(
         **{name: getattr(args, name) for name in OVERRIDE_SPEC})

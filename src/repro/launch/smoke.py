@@ -3,8 +3,10 @@
 Fans the (scenario x engine) catalog out over processes through
 ``repro.exp.run``: every registered scenario on the DES and fluid engines,
 the ``serve_*`` presets additionally on the serving and serving_jax
-engines (the latter serially in the driver process, sharing one
-compiled-program cache across presets). Each run
+engines. Only the pure-Python DES jobs go to the process pool; every other
+engine runs serially in the driver process, so no child process starts
+JAX work (on a machine with a chip, the process that touched JAX first
+holds it) and the JAX engines share one compiled-program cache. Each run
 persists one ``<scenario>-<engine>.runresult.npz``; the driver then
 *re-loads* every persisted RunResult in the output directory and validates
 the schema (``repro.exp.validate_run_result``: canonical metric names
@@ -33,14 +35,11 @@ import sys
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from repro.exp.runner import POOL_SAFE_ENGINES
+
 #: scenarios with this prefix also run on the serving engines (mirrors the
 #: retired ci.yml serving-presets bash loop)
 SERVING_PREFIX = "serve_"
-
-#: engines kept out of the process pool: serving_jax jobs share one
-#: in-process compiled-program cache (same FleetSpec -> no re-trace), where
-#: a pool worker would pay the XLA compile per process for zero overlap
-SINGLE_PROCESS_ENGINES = ("serving_jax",)
 
 
 def catalog(names: Optional[Sequence[str]] = None) -> List[Tuple[str, str]]:
@@ -84,8 +83,8 @@ def run_catalog(out_dir: pathlib.Path, *, quick: bool, seed: int,
                 names: Optional[Sequence[str]] = None) -> List[Dict]:
     payloads = [(n, e, quick, seed, str(out_dir))
                 for n, e in catalog(names)]
-    pooled = [p for p in payloads if p[1] not in SINGLE_PROCESS_ENGINES]
-    serial = [p for p in payloads if p[1] in SINGLE_PROCESS_ENGINES]
+    pooled = [p for p in payloads if p[1] in POOL_SAFE_ENGINES]
+    serial = [p for p in payloads if p[1] not in POOL_SAFE_ENGINES]
     results: List[Dict] = []
     if processes > 1 and pooled:
         from concurrent.futures import ProcessPoolExecutor
@@ -197,4 +196,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

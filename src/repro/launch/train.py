@@ -38,6 +38,9 @@ def main():
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.host_devices}")
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax
 
     from repro.checkpoint import Checkpointer

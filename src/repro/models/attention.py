@@ -221,9 +221,19 @@ def _flash_jnp_bwd(window, prefix_len, cap, scale, cq, ck, res, do):
 _flash_jnp.defvjp(_flash_jnp_fwd, _flash_jnp_bwd)
 
 
+def _untileable(what: str):
+    """Off the TPU a shape the kernels cannot tile takes the jnp path
+    (returns None); on the TPU it is an error, so a run that asked for the
+    kernels never measures the jnp path instead."""
+    if jax.default_backend() == "tpu":
+        raise ValueError(f"use_pallas: {what} does not tile the Pallas kernels")
+    return None
+
+
 def _pallas_attention(q, k, v, q_pos, k_pos, cfg, window):
-    """Route through the Pallas kernels (repro.kernels). Returns None when the
-    shapes don't tile (caller falls back to the jnp path)."""
+    """Route through the Pallas kernels (repro.kernels). Shapes that don't
+    tile return None off the TPU (caller takes the jnp path) and raise on
+    it (see ``_untileable``)."""
     from repro.kernels.decode_attention.ops import decode_attention
     from repro.kernels.flash_attention.ops import flash_attention
 
@@ -235,7 +245,7 @@ def _pallas_attention(q, k, v, q_pos, k_pos, cfg, window):
             0.0, NEG_INF).astype(jnp.float32)
         block_l = min(256, Sk)
         if Sk % block_l != 0:
-            return None
+            return _untileable(f"decode cache length {Sk}")
         o = decode_attention(q[:, 0].transpose(0, 1, 2), k.transpose(0, 2, 1, 3),
                              v.transpose(0, 2, 1, 3), bias,
                              softcap=cfg.attn_softcap, block_l=block_l)
@@ -244,7 +254,7 @@ def _pallas_attention(q, k, v, q_pos, k_pos, cfg, window):
     bq = min(128, Sq)
     bk = min(128, Sk)
     if Sq % bq or Sk % bk or Sq != Sk:
-        return None
+        return _untileable(f"self-attention over Sq={Sq}, Sk={Sk}")
     o = flash_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
         causal=True, window=window, softcap=cfg.attn_softcap,
@@ -336,21 +346,24 @@ def init_paged_entry(cfg: ModelConfig, spec: LayerSpec, n_phys_blocks: int,
     Logical cache slot ``s`` of a sequence lives at physical block
     ``page_table[s // block_size]``, offset ``s % block_size`` — the same
     ``slot = pos % L`` rolling invariant as the dense cache, just indirected
-    through the table. ``pos`` is stored per (block, offset) so gathering a
-    table row reproduces a dense cache entry bit-for-bit (NULL-block tail
-    included: zeros with pos=-1). ``quant="int8"`` stores K/V int8 with
-    rowwise (over hd) f32 scales (optim.compress.quantize_int8 layout).
+    through the table. K/V are kv-head-major, ``(n_phys_blocks, KV,
+    block_size, hd)``, so one head's block is the dense ``(block_size, hd)``
+    tile the paged kernel loads. ``pos`` is stored per (block, offset) so
+    gathering a table row reproduces a dense cache entry bit-for-bit
+    (NULL-block tail included: zeros with pos=-1). ``quant="int8"`` stores
+    K/V int8 with rowwise (over hd) f32 scales
+    (optim.compress.quantize_int8 layout).
     """
     KV, hd = cfg.num_kv_heads, cfg.head_dim
     dt = jnp.int8 if quant == "int8" else jnp.dtype(cfg.dtype)
     entry = {
-        "k": jnp.zeros((n_phys_blocks, block_size, KV, hd), dt),
-        "v": jnp.zeros((n_phys_blocks, block_size, KV, hd), dt),
+        "k": jnp.zeros((n_phys_blocks, KV, block_size, hd), dt),
+        "v": jnp.zeros((n_phys_blocks, KV, block_size, hd), dt),
         "pos": jnp.full((n_phys_blocks, block_size), -1, jnp.int32),
     }
     if quant == "int8":
-        entry["k_scale"] = jnp.zeros((n_phys_blocks, block_size, KV, 1), jnp.float32)
-        entry["v_scale"] = jnp.zeros((n_phys_blocks, block_size, KV, 1), jnp.float32)
+        entry["k_scale"] = jnp.zeros((n_phys_blocks, KV, block_size, 1), jnp.float32)
+        entry["v_scale"] = jnp.zeros((n_phys_blocks, KV, block_size, 1), jnp.float32)
     return entry
 
 
@@ -524,7 +537,7 @@ def attn_decode_paged(p, x, pool, cfg: ModelConfig, spec: LayerSpec, pos_vec,
     KV, hd = cfg.num_kv_heads, cfg.head_dim
     H = cfg.num_heads
     G = H // KV
-    bs = pool["k"].shape[1]
+    bs = pool["k"].shape[2]
     max_len = pages.shape[1] * bs
     L = cache_len_for(cfg, spec, max_len)
     P = L // bs
@@ -542,16 +555,17 @@ def attn_decode_paged(p, x, pool, cfg: ModelConfig, spec: LayerSpec, pos_vec,
     off = s % bs
     newk, newv = k[:, 0], v[:, 0]  # (B,KV,hd)
     pool = dict(pool)
+    # [blk, :, off] addresses (B, KV, hd) in the kv-head-major pool
     if quantized:
         qk, ksc = quantize_int8(newk)
         qv, vsc = quantize_int8(newv)
-        pool["k"] = pool["k"].at[blk, off].set(qk)
-        pool["v"] = pool["v"].at[blk, off].set(qv)
-        pool["k_scale"] = pool["k_scale"].at[blk, off].set(ksc)
-        pool["v_scale"] = pool["v_scale"].at[blk, off].set(vsc)
+        pool["k"] = pool["k"].at[blk, :, off].set(qk)
+        pool["v"] = pool["v"].at[blk, :, off].set(qv)
+        pool["k_scale"] = pool["k_scale"].at[blk, :, off].set(ksc)
+        pool["v_scale"] = pool["v_scale"].at[blk, :, off].set(vsc)
     else:
-        pool["k"] = pool["k"].at[blk, off].set(newk.astype(pool["k"].dtype))
-        pool["v"] = pool["v"].at[blk, off].set(newv.astype(pool["v"].dtype))
+        pool["k"] = pool["k"].at[blk, :, off].set(newk.astype(pool["k"].dtype))
+        pool["v"] = pool["v"].at[blk, :, off].set(newv.astype(pool["v"].dtype))
     pool["pos"] = pool["pos"].at[blk, off].set(pos_vec.astype(jnp.int32))
 
     tbl = pages[:, :P]  # (B,P)
@@ -566,11 +580,14 @@ def attn_decode_paged(p, x, pool, cfg: ModelConfig, spec: LayerSpec, pos_vec,
             softcap=cfg.attn_softcap)
         o = o[:, None]  # (B,1,H,hd)
     else:
-        ck = pool["k"][tbl].reshape(B, L, KV, hd)
-        cv = pool["v"][tbl].reshape(B, L, KV, hd)
+        def gather(leaf):  # (B,P,KV,bs,x) -> dense (B,L,KV,x)
+            g = leaf[tbl].swapaxes(2, 3)
+            return g.reshape(B, L, KV, g.shape[-1])
+
+        ck, cv = gather(pool["k"]), gather(pool["v"])
         if quantized:
-            ck = dequantize_int8(ck, pool["k_scale"][tbl].reshape(B, L, KV, 1))
-            cv = dequantize_int8(cv, pool["v_scale"][tbl].reshape(B, L, KV, 1))
+            ck = dequantize_int8(ck, gather(pool["k_scale"]))
+            cv = dequantize_int8(cv, gather(pool["v_scale"]))
         o = _paged_attention_jnp(
             q.reshape(B, 1, KV, G, hd), ck, cv, qpos, cpos,
             window=window, prefix_len=cfg.prefix_len, cap=cfg.attn_softcap,

@@ -90,7 +90,10 @@ class ModelConfig:
     attn_chunk_q: int = 512  # query-chunk for chunked (flash-style) jnp attention
     attn_chunk_k: int = 1024  # key-chunk
     flash_vjp: bool = False  # recompute-backward chunked attention (no O(S^2) residuals)
-    use_pallas: bool = False  # route hot ops through Pallas kernels (interpret on CPU)
+    # route hot ops through the Pallas kernels: compiled (Mosaic) on the TPU,
+    # interpret mode on the CPU; off keeps the pure-jnp path (the default, so
+    # the multi-pod dry-run lowers without Mosaic)
+    use_pallas: bool = False
 
     # derived ------------------------------------------------------------------
     @property

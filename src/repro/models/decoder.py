@@ -15,7 +15,7 @@ Params are nested dicts; ``init_shape`` produces the ShapeDtypeStruct tree via
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,10 +35,6 @@ from repro.models.common import (
 )
 from repro.models.config import LayerSpec, ModelConfig, block_structure
 from repro.parallel import logical
-
-
-def tree_stack(trees: List[Any]):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
 
 class DecoderLM:
@@ -84,14 +80,13 @@ class DecoderLM:
             params["ln0"] = init_norm(cfg)
         params["final_norm"] = init_norm(cfg)
         bkeys = jax.random.split(kB, self.n_blocks * self.block_size)
-        blocks = []
-        for j, spec in enumerate(self.specs):
-            trees = [
-                self._init_layer(bkeys[i * self.block_size + j], spec)
-                for i in range(self.n_blocks)
-            ]
-            blocks.append(tree_stack(trees))
-        params["blocks"] = blocks
+        # one vmapped layer init per block position (keys i*block_size + j):
+        # the same values as initializing the n_blocks layers one by one,
+        # in a program that does not grow with depth
+        params["blocks"] = [
+            jax.vmap(lambda k, spec=spec: self._init_layer(k, spec))(
+                bkeys[j::self.block_size])
+            for j, spec in enumerate(self.specs)]
         return params
 
     def init_shape(self):

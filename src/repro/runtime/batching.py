@@ -196,6 +196,7 @@ class ContinuousBatcher:
         self.last_tok = jnp.zeros((max_slots, 1), jnp.int32)
         self.queue: Deque[GenRequest] = deque()
         self.step_count = 0
+        self.last_logits = None  # (max_slots, V) of the latest decode step
         self._prefills: Dict[int, callable] = {}
 
         if kv_layout == "paged":
@@ -225,8 +226,10 @@ class ContinuousBatcher:
                 return self.model.decode_step_paged(
                     params, pools, tokens=toks, pos_vec=pos_vec, pages=table)
 
-            self._decode = jax.jit(
-                lambda c, t, p, tbl: decode_paged(params, c, t, p, tbl))
+            # params ride in as an argument: a closure would embed the
+            # weights in the program as constants; the pools are donated
+            # (each step replaces them)
+            self._decode = jax.jit(decode_paged, donate_argnums=1)
         else:
             # dense: each slot carries its own single-sequence cache (batch=1)
             # stacked on a leading slot axis; the decode step vmaps the
@@ -244,7 +247,7 @@ class ContinuousBatcher:
 
                 return jax.vmap(one, in_axes=(0, 0, 0))(cache_slots, toks, pos_vec)
 
-            self._decode = jax.jit(lambda c, t, p: decode_slotwise(params, c, t, p))
+            self._decode = jax.jit(decode_slotwise, donate_argnums=1)
 
     # ---------------------------------------------------------------- intake
 
@@ -300,20 +303,29 @@ class ContinuousBatcher:
             self._prefills[bucket] = jax.jit(prefill)
         return self._prefills[bucket]
 
+    def prefill(self, prompt: np.ndarray):
+        """Run the engine's compiled prefill for one prompt (the bucketed
+        program admission uses). Returns ``(logits (1, V), cache)`` with the
+        cache sized ``max_len`` for a batch of one."""
+        import jax.numpy as jnp
+
+        plen = len(prompt)
+        if self._bucketed:
+            bucket = self._bucket_for(plen)
+            toks = np.zeros(bucket, np.int32)
+            toks[:plen] = prompt
+        else:
+            bucket = plen  # one compiled prefill per distinct length
+            toks = np.asarray(prompt, np.int32)
+        return self._prefill_fn(bucket)(
+            self.params, jnp.asarray(toks)[None], jnp.asarray(plen, jnp.int32))
+
     def _admit(self, slot: int, req: GenRequest):
         import jax
         import jax.numpy as jnp
 
         plen = len(req.prompt)
-        if self._bucketed:
-            bucket = self._bucket_for(plen)
-            toks = np.zeros(bucket, np.int32)
-            toks[:plen] = req.prompt
-        else:
-            bucket = plen  # one compiled prefill per distinct length
-            toks = np.asarray(req.prompt, np.int32)
-        logits, cache1 = self._prefill_fn(bucket)(
-            self.params, jnp.asarray(toks)[None], jnp.asarray(plen, jnp.int32))
+        logits, cache1 = self.prefill(req.prompt)
         if self.kv_layout == "paged":
             self._scatter_paged(slot, req, cache1)
         else:
@@ -351,8 +363,9 @@ class ContinuousBatcher:
             KV, hd = entry["k"].shape[3:]
             P = L // bs
             tbl = jnp.asarray(write_row[:P])
-            vk = entry["k"][:, 0].reshape(nb, P, bs, KV, hd)
-            vv = entry["v"][:, 0].reshape(nb, P, bs, KV, hd)
+            # dense (nb, L, KV, hd) -> kv-head-major pages (nb, P, KV, bs, hd)
+            vk = entry["k"][:, 0].reshape(nb, P, bs, KV, hd).swapaxes(2, 3)
+            vv = entry["v"][:, 0].reshape(nb, P, bs, KV, hd).swapaxes(2, 3)
             vpos = entry["pos"].reshape(nb, P, bs)
             pool = dict(pool)
             if "k_scale" in pool:
@@ -377,24 +390,48 @@ class ContinuousBatcher:
         # head-of-line: FIFO admission waits for pages, never reorders
         return self.allocator.can_reserve(self._pages_for(self.queue[0]))
 
-    def step(self) -> int:
-        """Admit queued requests into free slots, then decode one token for
-        every active slot. Returns number of active slots."""
+    def decode_args(self):
+        """The decode step's arguments at the engine's current state:
+        ``(params, pools, tokens, pos_vec, page_table)`` paged,
+        ``(params, cache_slots, tokens, pos_vec)`` dense."""
         import jax.numpy as jnp
 
+        pos = jnp.asarray(self.pos, jnp.int32)
+        if self.kv_layout == "paged":
+            return (self.params, self.pools, self.last_tok, pos,
+                    jnp.asarray(self.allocator.table))
+        return self.params, self.cache_slots, self.last_tok, pos
+
+    def lower_decode(self):
+        """The jitted decode step lowered at the engine's current state, for
+        inspecting the program (its arguments, or which kernels it holds
+        once compiled) without running it."""
+        return self._decode.lower(*self.decode_args())
+
+    def admit(self) -> None:
+        """Prefill queued requests into free slots, head of line first,
+        while slots (and, paged, pages) last."""
         while self.queue and self.slots.n_free and self._can_admit_head():
             self._admit(self.slots.free_slot(), self.queue.popleft())
+
+    def step(self) -> int:
+        """Admit queued requests into free slots, then decode one token for
+        every active slot. Returns number of active slots. The step's
+        ``(max_slots, V)`` logits stay in ``last_logits`` (rows of free
+        slots are garbage)."""
+        import jax.numpy as jnp
+
+        self.admit()
         n_active = self.slots.n_active
         if n_active == 0:
             self.step_count += 1
             return 0
+        logits, new_cache = self._decode(*self.decode_args())
         if self.kv_layout == "paged":
-            logits, self.pools = self._decode(
-                self.pools, self.last_tok, jnp.asarray(self.pos, jnp.int32),
-                jnp.asarray(self.allocator.table))
+            self.pools = new_cache
         else:
-            logits, self.cache_slots = self._decode(
-                self.cache_slots, self.last_tok, jnp.asarray(self.pos, jnp.int32))
+            self.cache_slots = new_cache
+        self.last_logits = logits
         toks = np.asarray(jnp.argmax(logits, axis=-1))
         for slot, req in self.slots.items():
             req.tokens.append(int(toks[slot]))

@@ -60,6 +60,9 @@ class ElasticTrainer:
         self.ckpt = ckpt
         self.model_par = model_par
         self.devices = list(devices if devices is not None else jax.devices())
+        # rescales pick from the devices the trainer was given, never from
+        # the process's whole device list
+        self._pool = list(self.devices)
         self.log = log or (lambda s: None)
         self.watchdog = StragglerWatchdog()
         self.history = []  # (step, loss, n_devices)
@@ -130,6 +133,14 @@ class ElasticTrainer:
             return None
         return n_dev
 
+    def _survivors(self, n_dev: int):
+        """The first ``n_dev`` of the trainer's own devices: a shrink keeps a
+        prefix of the current mesh, a grow re-adds the ones it gave up."""
+        if not 1 <= n_dev <= len(self._pool):
+            raise ValueError(f"rescale to {n_dev} devices; the trainer was "
+                             f"given {len(self._pool)}")
+        return self._pool[:n_dev]
+
     def rescale(self, devices, step: int, state):
         """Drain -> checkpoint -> rebuild mesh -> reshard -> resume."""
         self.log(f"rescale at step {step}: {len(self.devices)} -> "
@@ -160,7 +171,7 @@ class ElasticTrainer:
             n_dev = self._plan_rescale(step, preempt_at.get(step))
             if n_dev is not None:
                 self._deferred_n_dev = None
-                state = self.rescale(jax.devices()[:n_dev], step, state)
+                state = self.rescale(self._survivors(n_dev), step, state)
                 self._last_rescale_step = step
             batch = jax.device_put(self.data.batch(step), self.batch_shardings)
             t0 = time.perf_counter()

@@ -3,6 +3,7 @@
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.configs import smoke_config
 from repro.models import build_model
@@ -106,3 +107,25 @@ def test_occupancy_and_waits_reported():
     waits = [r.wait for r in reqs]
     assert all(w is not None for w in waits)
     assert max(waits) > 0  # someone queued
+
+
+@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
+def test_decode_step_takes_weights_as_arguments(kv_layout):
+    """The jitted decode step receives the weights as arguments: a closure
+    would embed them in the program as constants (module text growing with
+    the weights, argument bytes leaving them out)."""
+    cfg = smoke_config("starcoder2-3b")
+    texts, param_bytes = [], []
+    for d_ff in (cfg.d_ff, 8 * cfg.d_ff):
+        model = build_model(cfg.replace(d_ff=d_ff))
+        params = model.init(jax.random.PRNGKey(0))
+        eng = ContinuousBatcher(model, params, max_slots=2, max_len=64,
+                                kv_layout=kv_layout)
+        lowered = eng.lower_decode()
+        nbytes = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(params))
+        mem = lowered.compile().memory_analysis()
+        assert mem.argument_size_in_bytes >= nbytes
+        texts.append(len(lowered.as_text()))
+        param_bytes.append(nbytes)
+    assert param_bytes[1] - param_bytes[0] > 1_000_000
+    assert abs(texts[1] - texts[0]) < 1_000, texts

@@ -127,6 +127,20 @@ def test_serving_sweep_pointwise():
     assert pt["short_avg_wait_s"] <= lo
 
 
+def test_sweep_keeps_non_des_engines_out_of_the_pool(monkeypatch):
+    """processes=N fans out DES only: any other engine runs in the calling
+    process (a JAX child of a parent holding the chip fails or hangs)."""
+    import concurrent.futures
+
+    def no_pool(*a, **k):
+        raise AssertionError("a non-DES engine went to a process pool")
+
+    monkeypatch.setattr(concurrent.futures, "ProcessPoolExecutor", no_pool)
+    sr = sweep("serve_yahoo", {"threshold": [0.4, 0.6]}, engine="serving",
+               processes=2, **SERVE_KW)
+    assert sr.shape == (2,) and sr.engine == "serving"
+
+
 def test_serving_beats_static_at_equal_budget():
     """The acceptance comparison behind benchmarks/serving_delay.py: the
     transient-backed preset beats a static fleet of equal-or-higher paid

@@ -95,22 +95,22 @@ def test_decode_attention(B, H, KV, L, hd, valid, dtype):
 # ------------------------------------------------------- paged decode attn
 
 def _paged_setup(B, KV, L, hd, bs, seed=0):
-    """Dense (B, L, KV, hd) K/V scattered into a paged pool with a distinct
-    physical block per (batch, logical page); blocks 0/1 are the NULL/TRASH
-    sentinels and stay zero."""
+    """Dense (B, L, KV, hd) K/V scattered into a (n_phys, KV, bs, hd) paged
+    pool with a distinct physical block per (batch, logical page); blocks
+    0/1 are the NULL/TRASH sentinels and stay zero."""
     rng = np.random.default_rng(seed)
     P = L // bs
     k = rng.normal(size=(B, L, KV, hd)).astype(np.float32)
     v = rng.normal(size=(B, L, KV, hd)).astype(np.float32)
     n_phys = 2 + B * P
-    kp = np.zeros((n_phys, bs, KV, hd), np.float32)
-    vp = np.zeros((n_phys, bs, KV, hd), np.float32)
+    kp = np.zeros((n_phys, KV, bs, hd), np.float32)  # kv-head-major pool
+    vp = np.zeros((n_phys, KV, bs, hd), np.float32)
     # shuffled assignment: physical order != logical order
     phys = rng.permutation(np.arange(2, n_phys)).reshape(B, P)
     for b in range(B):
         for j in range(P):
-            kp[phys[b, j]] = k[b, j * bs:(j + 1) * bs]
-            vp[phys[b, j]] = v[b, j * bs:(j + 1) * bs]
+            kp[phys[b, j]] = k[b, j * bs:(j + 1) * bs].swapaxes(0, 1)
+            vp[phys[b, j]] = v[b, j * bs:(j + 1) * bs].swapaxes(0, 1)
     return (jnp.asarray(k), jnp.asarray(v), jnp.asarray(kp), jnp.asarray(vp),
             jnp.asarray(phys.astype(np.int32)), rng)
 

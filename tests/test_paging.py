@@ -199,8 +199,8 @@ def test_int8_kv_pool_error_bound():
     rng = np.random.default_rng(11)
     B, H, KV, hd, bs, P, n_phys = 2, 4, 2, 32, 16, 4, 12
     L = P * bs
-    kp = jnp.asarray(rng.standard_normal((n_phys, bs, KV, hd)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_phys, bs, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((n_phys, KV, bs, hd)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((n_phys, KV, bs, hd)), jnp.float32)
     q = jnp.asarray(rng.standard_normal((B, H, hd)), jnp.float32)
     qk, ks = quantize_int8(kp)
     # elementwise bound: |x - deq(x)| <= scale/2 = amax/254

@@ -46,6 +46,25 @@ def test_elastic_trainer_rescale_and_resume(tmp_path):
     assert all(np.isfinite(h[1]) for h in tr3.history)
 
 
+def test_elastic_rescale_picks_survivors_from_own_devices(tmp_path):
+    """A trainer given devices 4..7 shrinks onto 4..5, never onto the
+    process's first devices, and refuses to grow past what it was given."""
+    cfg = smoke_config("starcoder2-3b").replace(num_microbatches=2)
+    model = build_model(cfg)
+    data = SyntheticBatches(cfg, global_batch=8, seq_len=32, seed=0)
+    own = jax.devices()[4:8]
+    tr = ElasticTrainer(model, AdamW(lr=constant_schedule(3e-3)), data,
+                        Checkpointer(tmp_path, keep=2), model_par=2,
+                        devices=own)
+    tr.run(4, preempt_at={2: 2}, checkpoint_every=0)
+    assert tr.rescales == 1 and tr.devices == own[:2]
+    spanned = {d for s in jax.tree.leaves(tr.state_shardings)
+               for d in s.device_set}
+    assert spanned == set(own[:2])
+    with pytest.raises(ValueError):
+        tr._survivors(5)
+
+
 def test_abstract_state_matches_live_constructor():
     """ElasticTrainer cold-restore regression: the abstract TrainState must
     be eval-shaped from the same ``opt.init_state`` the live path calls —

@@ -180,3 +180,27 @@ def test_committed_serving_baseline_shape():
     for mspec in spec["metrics"].values():
         assert "value" in mspec
         assert mspec.get("direction", "both") in ("lower", "higher", "both")
+
+
+# ------------------------------------------------------- compile cache dir
+
+def test_compile_cache_dir_follows_env_else_repo(monkeypatch):
+    """Launchers keep JAX's persistent cache in $JAX_COMPILATION_CACHE_DIR
+    (set nothing then) or at the fixed in-checkout ``.jax_cache``."""
+    import jax
+
+    from repro.launch.cache import DEFAULT_CACHE_DIR, enable_compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert enable_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == was
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert enable_compile_cache() == str(DEFAULT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert DEFAULT_CACHE_DIR == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
