@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device, in
+percent (fleet cells)."""
+
+
+def read(d):
+    t = d["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
