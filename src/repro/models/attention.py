@@ -550,48 +550,55 @@ def attn_decode_paged(p, x, pool, cfg: ModelConfig, spec: LayerSpec, pos_vec,
         q = apply_rope(q, qpos, cfg.rope_theta)
         k = apply_rope(k, qpos, cfg.rope_theta)
 
-    s = jnp.mod(pos_vec, L)
-    blk = jnp.take_along_axis(pages, (s // bs)[:, None], axis=1)[:, 0]  # (B,)
-    off = s % bs
-    newk, newv = k[:, 0], v[:, 0]  # (B,KV,hd)
-    pool = dict(pool)
-    # [blk, :, off] addresses (B, KV, hd) in the kv-head-major pool
-    if quantized:
-        qk, ksc = quantize_int8(newk)
-        qv, vsc = quantize_int8(newv)
-        pool["k"] = pool["k"].at[blk, :, off].set(qk)
-        pool["v"] = pool["v"].at[blk, :, off].set(qv)
-        pool["k_scale"] = pool["k_scale"].at[blk, :, off].set(ksc)
-        pool["v_scale"] = pool["v_scale"].at[blk, :, off].set(vsc)
-    else:
-        pool["k"] = pool["k"].at[blk, :, off].set(newk.astype(pool["k"].dtype))
-        pool["v"] = pool["v"].at[blk, :, off].set(newv.astype(pool["v"].dtype))
-    pool["pos"] = pool["pos"].at[blk, off].set(pos_vec.astype(jnp.int32))
+    with jax.named_scope("kv_write"):
+        s = jnp.mod(pos_vec, L)
+        blk = jnp.take_along_axis(pages, (s // bs)[:, None], axis=1)[:, 0]  # (B,)
+        off = s % bs
+        newk, newv = k[:, 0], v[:, 0]  # (B,KV,hd)
+        pool = dict(pool)
+        # [blk, :, off] addresses (B, KV, hd) in the kv-head-major pool
+        if quantized:
+            qk, ksc = quantize_int8(newk)
+            qv, vsc = quantize_int8(newv)
+            pool["k"] = pool["k"].at[blk, :, off].set(qk)
+            pool["v"] = pool["v"].at[blk, :, off].set(qv)
+            pool["k_scale"] = pool["k_scale"].at[blk, :, off].set(ksc)
+            pool["v_scale"] = pool["v_scale"].at[blk, :, off].set(vsc)
+        else:
+            pool["k"] = pool["k"].at[blk, :, off].set(
+                newk.astype(pool["k"].dtype))
+            pool["v"] = pool["v"].at[blk, :, off].set(
+                newv.astype(pool["v"].dtype))
+        pool["pos"] = pool["pos"].at[blk, off].set(pos_vec.astype(jnp.int32))
 
     tbl = pages[:, :P]  # (B,P)
-    cpos = pool["pos"][tbl].reshape(B, L)
-    if cfg.use_pallas:
-        bias = jnp.where(
-            allow_mask(qpos, cpos, window=window, prefix_len=cfg.prefix_len),
-            0.0, NEG_INF).astype(jnp.float32)[:, 0]  # (B,L)
-        o = paged_decode_attention(
-            q[:, 0], pool["k"], pool["v"], tbl, bias,
-            k_scale=pool.get("k_scale"), v_scale=pool.get("v_scale"),
-            softcap=cfg.attn_softcap)
-        o = o[:, None]  # (B,1,H,hd)
-    else:
-        def gather(leaf):  # (B,P,KV,bs,x) -> dense (B,L,KV,x)
-            g = leaf[tbl].swapaxes(2, 3)
-            return g.reshape(B, L, KV, g.shape[-1])
+    with jax.named_scope("kv_mask"):
+        cpos = pool["pos"][tbl].reshape(B, L)
+        if cfg.use_pallas:
+            bias = jnp.where(
+                allow_mask(qpos, cpos, window=window,
+                           prefix_len=cfg.prefix_len),
+                0.0, NEG_INF).astype(jnp.float32)[:, 0]  # (B,L)
+    with jax.named_scope("paged_attention"):
+        if cfg.use_pallas:
+            o = paged_decode_attention(
+                q[:, 0], pool["k"], pool["v"], tbl, bias,
+                k_scale=pool.get("k_scale"), v_scale=pool.get("v_scale"),
+                softcap=cfg.attn_softcap)
+            o = o[:, None]  # (B,1,H,hd)
+        else:
+            def gather(leaf):  # (B,P,KV,bs,x) -> dense (B,L,KV,x)
+                g = leaf[tbl].swapaxes(2, 3)
+                return g.reshape(B, L, KV, g.shape[-1])
 
-        ck, cv = gather(pool["k"]), gather(pool["v"])
-        if quantized:
-            ck = dequantize_int8(ck, gather(pool["k_scale"]))
-            cv = dequantize_int8(cv, gather(pool["v_scale"]))
-        o = _paged_attention_jnp(
-            q.reshape(B, 1, KV, G, hd), ck, cv, qpos, cpos,
-            window=window, prefix_len=cfg.prefix_len, cap=cfg.attn_softcap,
-            scale=hd**-0.5)
-        o = o.reshape(B, 1, H, hd)
+            ck, cv = gather(pool["k"]), gather(pool["v"])
+            if quantized:
+                ck = dequantize_int8(ck, gather(pool["k_scale"]))
+                cv = dequantize_int8(cv, gather(pool["v_scale"]))
+            o = _paged_attention_jnp(
+                q.reshape(B, 1, KV, G, hd), ck, cv, qpos, cpos,
+                window=window, prefix_len=cfg.prefix_len,
+                cap=cfg.attn_softcap, scale=hd**-0.5)
+            o = o.reshape(B, 1, H, hd)
     y = _out(p, o, cfg)
     return y, pool

@@ -227,15 +227,21 @@ class DecoderLM:
             x, caches = jax.lax.scan(sb, x, params["blocks"])
             return x, jnp.zeros((), jnp.float32), caches
         # decode / decode_paged (pos is a scalar for decode, a (B,) vector of
-        # per-slot positions for decode_paged; pages threads the page table)
+        # per-slot positions for decode_paged; pages threads the page table).
+        # Named scopes: what the scan does itself (slicing each block's
+        # params and cache out of the stack, writing the new cache back)
+        # falls under ``layer_scan`` alone, the block's own work under
+        # ``layer_scan/.../layer_body``
         def sb(xc, inp):
             bp, bc = inp
-            xo, _, nc = self._block_body(
-                xc, bp, bc, positions=positions, mode=mode, pos=pos,
-                pages=pages)
+            with jax.named_scope("layer_body"):
+                xo, _, nc = self._block_body(
+                    xc, bp, bc, positions=positions, mode=mode, pos=pos,
+                    pages=pages)
             return xo, nc
 
-        x, caches = jax.lax.scan(sb, x, (params["blocks"], cache))
+        with jax.named_scope("layer_scan"):
+            x, caches = jax.lax.scan(sb, x, (params["blocks"], cache))
         return x, jnp.zeros((), jnp.float32), caches
 
     # ------------------------------------------------------------- embeddings

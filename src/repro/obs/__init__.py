@@ -9,11 +9,15 @@ Three pieces, one observability spine (see ROADMAP "repro/obs"):
                ``runtime/serving``) and reconstructed post-hoc for
                ``runtime/serving_jax`` from its per-tick event-count series
                — one schema, so event streams diff across engines
-  trace.py   — zero-cost-when-disabled span/counter tracer with Chrome
-               trace-event JSON export (open in Perfetto: ui.perfetto.dev)
-  metrics.py — counters/gauges/histograms registry snapshotted into
+  trace.py   — zero-cost-when-disabled span/counter tracer in simulated
+               ticks, with Chrome trace-event JSON export (open in
+               Perfetto: ui.perfetto.dev)
+  metrics.py — counters/histograms registry snapshotted into
                ``RunResult.meta["obs"]`` (jit-cache hit/miss, compile vs
-               steady wall time around ``serving_jax.get_program``)
+               steady wall time around ``serving_jax.get_program``), and
+               ``span``: a profiler span on the device trace's clock
+               (``jax.profiler.TraceAnnotation``) around a stretch of
+               host work in the replica engine and the fleet program
 """
 
 from repro.obs.events import (ADMIT, DISPLACE, DRAIN, EVENT_TYPES,  # noqa: F401
@@ -22,7 +26,7 @@ from repro.obs.events import (ADMIT, DISPLACE, DRAIN, EVENT_TYPES,  # noqa: F401
                               check_replica_lifecycles,
                               check_transient_conservation,
                               diff_event_streams, events_from_counts)
-from repro.obs.metrics import (REGISTRY, Counter, Gauge,  # noqa: F401
-                               Histogram, MetricsRegistry, timed)
+from repro.obs.metrics import (REGISTRY, Counter,  # noqa: F401
+                               Histogram, MetricsRegistry, span)
 from repro.obs.trace import (Tracer, trace_from_run_result,  # noqa: F401
                              validate_trace_events, validate_trace_file)

@@ -1,4 +1,4 @@
-"""Counters / gauges / histograms registry for run-level telemetry.
+"""Counters and histograms for run-level telemetry, and program spans.
 
 A :class:`MetricsRegistry` is a get-or-create namespace of named
 instruments whose :meth:`~MetricsRegistry.snapshot` is a plain-JSON dict —
@@ -7,17 +7,21 @@ the shape stored under ``RunResult.meta["obs"]``. The module-level
 jit-cache hit/miss counters and compile-vs-steady execution histograms
 around ``get_program`` (the PR-6 ``serving_scale`` split, generalized to
 every serving_jax run, sweep cube, and smoke job).
+
+:func:`span` marks a stretch of host work for the profiler
+(``jax.profiler.TraceAnnotation``): it lands on the host plane of the same
+trace as the device's ops, on the same clock, and nests under whatever
+span is open on its thread. It keeps nothing itself — a span exists only
+in a profile, and costs one check when no profile is being taken.
 """
 
 from __future__ import annotations
 
 import math
-import time
 from contextlib import contextmanager
 from typing import Dict, List
 
-__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
-           "timed"]
+__all__ = ["Counter", "Histogram", "MetricsRegistry", "REGISTRY", "span"]
 
 
 class Counter:
@@ -33,21 +37,6 @@ class Counter:
     @property
     def value(self) -> int:
         return self._n
-
-
-class Gauge:
-    __slots__ = ("name", "_v")
-
-    def __init__(self, name: str) -> None:
-        self.name = name
-        self._v = 0.0
-
-    def set(self, v: float) -> None:
-        self._v = float(v)
-
-    @property
-    def value(self) -> float:
-        return self._v
 
 
 def _quantile(sorted_vals: List[float], q: float) -> float:
@@ -104,9 +93,6 @@ class MetricsRegistry:
     def counter(self, name: str) -> Counter:
         return self._get(name, Counter)
 
-    def gauge(self, name: str) -> Gauge:
-        return self._get(name, Gauge)
-
     def histogram(self, name: str) -> Histogram:
         return self._get(name, Histogram)
 
@@ -114,8 +100,6 @@ class MetricsRegistry:
         return {
             "counters": {n: i.value for n, i in self._instruments.items()
                          if isinstance(i, Counter)},
-            "gauges": {n: i.value for n, i in self._instruments.items()
-                       if isinstance(i, Gauge)},
             "histograms": {n: i.snapshot()
                            for n, i in self._instruments.items()
                            if isinstance(i, Histogram)},
@@ -130,11 +114,10 @@ REGISTRY = MetricsRegistry()
 
 
 @contextmanager
-def timed(name: str, registry: MetricsRegistry = REGISTRY):
-    """Observe the wrapped block's wall time (perf_counter seconds) into
-    ``registry.histogram(name)``."""
-    t0 = time.perf_counter()
-    try:
+def span(name: str, **ids):
+    """A profiler span named ``name`` around the block; ``ids`` (e.g.
+    ``rid=7``) ride on it as the trace event's stats."""
+    from jax.profiler import TraceAnnotation  # numpy-only importers
+
+    with TraceAnnotation(name, **ids):
         yield
-    finally:
-        registry.histogram(name).observe(time.perf_counter() - t0)

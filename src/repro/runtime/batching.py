@@ -321,25 +321,34 @@ class ContinuousBatcher:
             self.params, jnp.asarray(toks)[None], jnp.asarray(plen, jnp.int32))
 
     def _admit(self, slot: int, req: GenRequest):
+        """One request into ``slot``: prefill, pages and scatter, its first
+        token on the host, and the slot's state. The ``batcher.admit`` span
+        (``rid``) is the request's admission and first-token stamp in a
+        profile."""
         import jax
         import jax.numpy as jnp
 
-        plen = len(req.prompt)
-        logits, cache1 = self.prefill(req.prompt)
-        if self.kv_layout == "paged":
-            self._scatter_paged(slot, req, cache1)
-        else:
-            # cache1 leaves match a slot cache exactly (batch=1)
-            self.cache_slots = jax.tree.map(
-                lambda all_slots, one: all_slots.at[slot].set(one),
-                self.cache_slots, cache1)
-        tok = int(jnp.argmax(logits[0]))
-        req.tokens.append(tok)
-        req.start_step = self.step_count
-        self.last_tok = self.last_tok.at[slot, 0].set(tok)
-        self.pos[slot] = plen
-        self.remaining[slot] = req.max_new - 1
-        self.slots.place(slot, req)
+        from repro.obs.metrics import span
+
+        with span("batcher.admit", rid=req.rid):
+            plen = len(req.prompt)
+            with span("batcher.prefill"):
+                logits, cache1 = self.prefill(req.prompt)
+            with span("batcher.scatter"):
+                if self.kv_layout == "paged":
+                    self._scatter_paged(slot, req, cache1)
+                else:
+                    # cache1 leaves match a slot cache exactly (batch=1)
+                    self.cache_slots = jax.tree.map(
+                        lambda all_slots, one: all_slots.at[slot].set(one),
+                        self.cache_slots, cache1)
+            tok = int(jnp.argmax(logits[0]))
+            req.tokens.append(tok)
+            req.start_step = self.step_count
+            self.last_tok = self.last_tok.at[slot, 0].set(tok)
+            self.pos[slot] = plen
+            self.remaining[slot] = req.max_new - 1
+            self.slots.place(slot, req)
 
     def _scatter_paged(self, slot: int, req: GenRequest, cache1):
         """Reserve the slot's pages and scatter the prefill cache into the
@@ -421,30 +430,38 @@ class ContinuousBatcher:
         slots are garbage)."""
         import jax.numpy as jnp
 
-        self.admit()
-        n_active = self.slots.n_active
-        if n_active == 0:
-            self.step_count += 1
-            return 0
-        logits, new_cache = self._decode(*self.decode_args())
-        if self.kv_layout == "paged":
-            self.pools = new_cache
-        else:
-            self.cache_slots = new_cache
-        self.last_logits = logits
-        toks = np.asarray(jnp.argmax(logits, axis=-1))
-        for slot, req in self.slots.items():
-            req.tokens.append(int(toks[slot]))
-            self.pos[slot] += 1
-            self.remaining[slot] -= 1
-            if self.remaining[slot] <= 0 or self.pos[slot] >= self.max_len - 1:
-                req.finish_step = self.step_count
-                self.slots.release(slot)  # freed for next step
+        from repro.obs.metrics import span
+
+        with span("batcher.step"):
+            self.admit()
+            n_active = self.slots.n_active
+            if n_active == 0:
+                self.step_count += 1
+                return 0
+            with span("batcher.dispatch"):
+                logits, new_cache = self._decode(*self.decode_args())
                 if self.kv_layout == "paged":
-                    self.allocator.free(slot)  # pages back to the pool
-        self.last_tok = jnp.asarray(toks[:, None], jnp.int32)
-        self.step_count += 1
-        return n_active
+                    self.pools = new_cache
+                else:
+                    self.cache_slots = new_cache
+                self.last_logits = logits
+                toks = jnp.argmax(logits, axis=-1)
+            with span("batcher.readback"):  # the host waits on the device
+                toks = np.asarray(toks)
+            with span("batcher.bookkeep"):
+                for slot, req in self.slots.items():
+                    req.tokens.append(int(toks[slot]))
+                    self.pos[slot] += 1
+                    self.remaining[slot] -= 1
+                    if (self.remaining[slot] <= 0
+                            or self.pos[slot] >= self.max_len - 1):
+                        req.finish_step = self.step_count
+                        self.slots.release(slot)  # freed for next step
+                        if self.kv_layout == "paged":
+                            self.allocator.free(slot)  # pages back to the pool
+                self.last_tok = jnp.asarray(toks[:, None], jnp.int32)
+            self.step_count += 1
+            return n_active
 
     def run(self, until_empty: bool = True, max_steps: int = 10_000):
         """Step the engine. With ``until_empty`` (the default) stepping

@@ -16,6 +16,8 @@ the Perfetto tracer, and the metrics registry.
     deliberately broken files);
   * RunResult validation gates the new telemetry: negative wall times,
     serving_jax results without ``meta["obs"]`` / ``meta["fleet_spec"]``;
+  * the program's profiler spans (``obs.span``) nest as the engine's step
+    does, and the fleet program carries one named scope per tick phase;
   * the smoke driver persists a machine-readable ``smoke_summary.json``.
 """
 
@@ -33,7 +35,7 @@ from repro.obs import (ADMIT, DRAIN, EVENT_TYPES, HEDGE, HEDGE_WIN,
                        MetricsRegistry,
                        Tracer, check_replica_lifecycles,
                        check_transient_conservation, diff_event_streams,
-                       events_from_counts, timed, trace_from_run_result,
+                       events_from_counts, span, trace_from_run_result,
                        validate_trace_events, validate_trace_file)
 from repro.runtime import serving_jax as sj
 from repro.runtime.serving import (ElasticServingFleet, Request,
@@ -357,22 +359,100 @@ def test_metrics_registry_snapshot_and_kinds():
     reg = MetricsRegistry()
     reg.counter("hits").inc()
     reg.counter("hits").inc(2)
-    reg.gauge("depth").set(4.5)
     for v in range(1, 101):
         reg.histogram("lat").observe(float(v))
     snap = reg.snapshot()
     assert snap["counters"]["hits"] == 3
-    assert snap["gauges"]["depth"] == 4.5
     h = snap["histograms"]["lat"]
     assert h["count"] == 100 and h["p50"] == 50.0 and h["p99"] == 99.0
     with pytest.raises(TypeError):
-        reg.gauge("hits")  # registered as a counter
-    with timed("block_s", reg):
+        reg.histogram("hits")  # registered as a counter
+    with span("block", rid=3):  # no profile is being taken: a no-op
         pass
-    assert reg.snapshot()["histograms"]["block_s"]["count"] == 1
     reg.reset()
-    assert reg.snapshot() == {"counters": {}, "gauges": {},
-                              "histograms": {}}
+    assert reg.snapshot() == {"counters": {}, "histograms": {}}
+
+
+def _profile_events(run, tmp_path):
+    """Host events of a CPU profile of ``run()``: (name, start, end,
+    stats) of every ``batcher.*`` / ``serving_jax.*`` span."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                name = ev.name.split("#", 1)[0]
+                if name.startswith(("batcher.", "serving_jax.")):
+                    s = int(ev.start_ns)
+                    out.append((name, s, s + int(ev.duration_ns),
+                                dict(ev.stats)))
+    return out
+
+
+def _within(events, name, s, e):
+    return [x for x in events if x[0] == name and s <= x[1] and x[2] <= e]
+
+
+def test_batcher_spans_in_a_profile(tmp_path):
+    """A profile of a tiny paged engine shows each decoding step's spans
+    nested in ``batcher.step``, and one ``batcher.admit`` per request, with
+    its ``rid``, holding the request's prefill and scatter."""
+    import jax
+
+    from repro.configs import smoke_config
+    from repro.models import build_model
+    from repro.runtime.batching import ContinuousBatcher, GenRequest
+
+    cfg = smoke_config("starcoder2-3b")
+    model = build_model(cfg)
+    b = ContinuousBatcher(model, model.init(jax.random.PRNGKey(0)),
+                          max_slots=2, max_len=64, kv_layout="paged")
+    reqs = [GenRequest(rid, np.arange(1, 9, dtype=np.int32), 3)
+            for rid in (11, 12, 13)]
+    for r in reqs:
+        b.submit(r)
+    ev = _profile_events(b.run, tmp_path)
+    steps = [x for x in ev if x[0] == "batcher.step"]
+    assert len(steps) == b.step_count
+    decoding = [x for x in steps
+                if _within(ev, "batcher.dispatch", x[1], x[2])]
+    assert decoding
+    for _, s, e, _ in decoding:
+        for kid in ("batcher.dispatch", "batcher.readback",
+                    "batcher.bookkeep"):
+            assert len(_within(ev, kid, s, e)) == 1, kid
+    admits = [x for x in ev if x[0] == "batcher.admit"]
+    assert sorted(x[3]["rid"] for x in admits) == [11, 12, 13]
+    for _, s, e, _ in admits:
+        assert len(_within(ev, "batcher.prefill", s, e)) == 1
+        assert len(_within(ev, "batcher.scatter", s, e)) == 1
+        assert any(x[1] <= s and e <= x[2] for x in steps)
+
+
+def test_fleet_program_is_named_and_holds_every_phase_scope():
+    import jax
+
+    cfg, reqs, _, T = _DET_CASES[1]
+    spec = sj.make_spec(cfg, n_requests=len(reqs), max_ticks=T,
+                        max_arrivals_per_tick=1)
+    consts = sj.build_consts(spec, reqs, np.zeros(T, int))
+    text = sj.get_program(spec).lower(
+        sj.make_params(cfg), consts,
+        jax.random.PRNGKey(0)).as_text(debug_info=True)
+    assert "jit_fleet_point" in text
+    for phase in ("pin", "flush", "provision", "route", "control", "revoke",
+                  "hedge", "advance", "record"):
+        assert f"tick.{phase}/" in text, phase
 
 
 def test_serving_jax_run_records_obs_telemetry():
