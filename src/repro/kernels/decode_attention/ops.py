@@ -14,17 +14,28 @@ def decode_attention(q, k, v, bias, *, softcap=0.0, block_l=256,
                                 interpret=interpret_mode(interpret))
 
 
+def live_pages(pos, block_size: int, n_pages: int):
+    """Logical pages holding positions a sequence whose new token sits at
+    ``pos`` can attend to: ``min(P, pos // block_size + 1)``. A rolling
+    local layer that has wrapped (``pos >= P * block_size``) gives all P.
+    Works on NumPy and JAX arrays alike (the engine's host-side counter and
+    the decode step use the same rule)."""
+    return (pos // block_size + 1).clip(max=n_pages)
+
+
 def paged_decode_attention(q, k_pages, v_pages, page_table, bias, *,
-                           k_scale=None, v_scale=None, softcap=0.0,
-                           interpret=None):
+                           n_pages=None, k_scale=None, v_scale=None,
+                           softcap=0.0, interpret=None):
     """Decode attention against a paged KV pool — the gather through
-    ``page_table`` happens inside the kernel (scalar-prefetch BlockSpecs).
+    ``page_table`` happens inside the kernel (scalar-prefetched table,
+    manual page DMAs).
 
     q: (B,H,hd); k_pages/v_pages: (n_phys_blocks, KV, block_size, hd);
     page_table: (B,P) int32; bias: (B, P*block_size) f32 additive mask.
-    k_scale/v_scale: (n_phys_blocks, KV, block_size, 1) f32 when the pools
-    are int8 (in-kernel dequantization). Returns (B,H,hd)."""
+    n_pages: (B,) int32 pages each sequence walks (``live_pages``), or None
+    for all P. k_scale/v_scale: (n_phys_blocks, KV, block_size, 1) f32 when
+    the pools are int8 (in-kernel dequantization). Returns (B,H,hd)."""
     return paged_decode_attention_fwd(q, k_pages, v_pages, page_table, bias,
-                                      k_scale=k_scale, v_scale=v_scale,
-                                      softcap=softcap,
+                                      n_pages=n_pages, k_scale=k_scale,
+                                      v_scale=v_scale, softcap=softcap,
                                       interpret=interpret_mode(interpret))

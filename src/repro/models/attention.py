@@ -527,10 +527,12 @@ def attn_decode_paged(p, x, pool, cfg: ModelConfig, spec: LayerSpec, pos_vec,
     shapes — same as the dense engine); the runtime points inactive slots'
     rows at the shared TRASH block so their garbage writes are never read.
     With ``cfg.use_pallas`` the attention runs in the paged Pallas kernel
-    (gather inside the kernel); otherwise the pool is gathered to a dense
-    (B,L) cache and fed through the jnp path (the oracle semantics).
+    (gather inside the kernel, each slot walking only the pages its
+    position reaches: ``live_pages``); otherwise the pool is gathered to a
+    dense (B,L) cache and fed through the jnp path (the oracle semantics).
     """
-    from repro.kernels.decode_attention.ops import paged_decode_attention
+    from repro.kernels.decode_attention.ops import (live_pages,
+                                                    paged_decode_attention)
     from repro.optim.compress import dequantize_int8, quantize_int8
 
     B = x.shape[0]
@@ -583,6 +585,7 @@ def attn_decode_paged(p, x, pool, cfg: ModelConfig, spec: LayerSpec, pos_vec,
         if cfg.use_pallas:
             o = paged_decode_attention(
                 q[:, 0], pool["k"], pool["v"], tbl, bias,
+                n_pages=live_pages(pos_vec, bs, P),
                 k_scale=pool.get("k_scale"), v_scale=pool.get("v_scale"),
                 softcap=cfg.attn_softcap)
             o = o[:, None]  # (B,1,H,hd)

@@ -438,6 +438,8 @@ class ContinuousBatcher:
             if n_active == 0:
                 self.step_count += 1
                 return 0
+            if self.kv_layout == "paged":
+                self._count_pages()
             with span("batcher.dispatch"):
                 logits, new_cache = self._decode(*self.decode_args())
                 if self.kv_layout == "paged":
@@ -457,11 +459,27 @@ class ContinuousBatcher:
                             or self.pos[slot] >= self.max_len - 1):
                         req.finish_step = self.step_count
                         self.slots.release(slot)  # freed for next step
+                        # a free slot's position only addresses its
+                        # discarded write; 0 keeps its kernel walk to a block
+                        self.pos[slot] = 0
                         if self.kv_layout == "paged":
                             self.allocator.free(slot)  # pages back to the pool
                 self.last_tok = jnp.asarray(toks[:, None], jnp.int32)
             self.step_count += 1
             return n_active
+
+    def _count_pages(self) -> None:
+        """Counters of the paged kernel's walk in this step:
+        ``batcher.kv_pages_walked`` adds the live pages of every slot (the
+        rule the decode step hands the kernel, on a full-length layer),
+        ``batcher.kv_pages_table`` the whole table's pages."""
+        from repro.kernels.decode_attention.ops import live_pages
+        from repro.obs.metrics import REGISTRY
+
+        P = self.pages_per_slot
+        REGISTRY.counter("batcher.kv_pages_walked").inc(
+            int(live_pages(self.pos, self.kv_block_size, P).sum()))
+        REGISTRY.counter("batcher.kv_pages_table").inc(self.max_slots * P)
 
     def run(self, until_empty: bool = True, max_steps: int = 10_000):
         """Step the engine. With ``until_empty`` (the default) stepping

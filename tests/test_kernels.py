@@ -160,6 +160,105 @@ def test_paged_decode_attention_int8():
     assert float(jnp.max(jnp.abs(o32 - o_ref))) < 0.05
 
 
+def _live_batch(pool, softcap):
+    """One batch of every kind of slot the engine hands the kernel, over a
+    table long enough for four compute blocks (L=1024 in 16-token pages):
+    one page, a page-boundary straddle, a block-boundary straddle, the full
+    table, a rolling local layer that has wrapped (pos >= L, every position
+    in the window), and a free slot (pos 0, its row all TRASH, whose pages
+    each hold the free slot's position 0 at offset 0)."""
+    from repro.kernels.decode_attention.ops import (live_pages,
+                                                    paged_decode_attention)
+    from repro.kernels.decode_attention.ref import paged_decode_attention_ref
+    from repro.optim.compress import quantize_int8
+    from repro.runtime.paging import TRASH_BLOCK
+
+    B, H, KV, L, hd, bs = 6, 4, 2, 1024, 32, 16
+    P = L // bs
+    _, _, kp, vp, tbl, rng = _paged_setup(B, KV, L, hd, bs, seed=5)
+    kp = kp.at[TRASH_BLOCK].set(rng.normal(size=kp.shape[1:]))
+    vp = vp.at[TRASH_BLOCK].set(rng.normal(size=vp.shape[1:]))
+    pos = np.array([3, 16, 256, L - 1, L + 100, 0])
+    tbl = tbl.at[5].set(TRASH_BLOCK)
+    allowed = np.arange(L)[None] <= pos[:, None]
+    allowed[5] = np.arange(L) % bs == 0
+    bias = jnp.asarray(np.where(allowed, 0.0, NEG_INF).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
+    kw = dict(softcap=softcap)
+    if pool == "int8":
+        kp, ks = quantize_int8(kp)
+        vp, vs = quantize_int8(vp)
+        kw.update(k_scale=ks, v_scale=vs)
+    elif pool == "bf16":
+        q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+    n_pages = live_pages(jnp.asarray(pos, jnp.int32), bs, P)
+    np.testing.assert_array_equal(np.asarray(n_pages), [1, 2, 17, P, P, 1])
+    o = paged_decode_attention(q, kp, vp, tbl, bias, n_pages=n_pages,
+                               interpret=True, **kw)
+    return o, paged_decode_attention_ref(q, kp, vp, tbl, bias, **kw), (
+        q, kp, vp, tbl, bias, kw, P)
+
+
+@pytest.mark.parametrize("pool,softcap", [
+    ("f32", 0.0), ("f32", 30.0), ("bf16", 0.0), ("int8", 0.0),
+    ("int8", 30.0),
+])
+def test_paged_decode_attention_live_pages(pool, softcap):
+    """The kernel walks only each slot's live pages and still matches the
+    oracle, which reads the whole table."""
+    o, o_ref, _ = _live_batch(pool, softcap)
+    tol = 2e-2 if pool == "bf16" else 2e-5
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(o_ref, np.float32), atol=tol,
+                               rtol=tol)
+
+
+def test_paged_decode_attention_all_pages_by_default():
+    """``n_pages=None`` is the whole table, bit for bit."""
+    from repro.kernels.decode_attention.ops import paged_decode_attention
+
+    _, _, (q, kp, vp, tbl, bias, kw, P) = _live_batch("f32", 0.0)
+    full = jnp.full((q.shape[0],), P, jnp.int32)
+    o_none = paged_decode_attention(q, kp, vp, tbl, bias, interpret=True,
+                                    **kw)
+    o_full = paged_decode_attention(q, kp, vp, tbl, bias, n_pages=full,
+                                    interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(o_none), np.asarray(o_full))
+
+
+def test_paged_decode_attention_skips_dead_blocks():
+    """Blocks past a slot's live pages are never read: NaN there leaves the
+    output as it was."""
+    from repro.kernels.decode_attention.kernel import pages_per_block
+    from repro.kernels.decode_attention.ops import (live_pages,
+                                                    paged_decode_attention)
+
+    o, _, (q, kp, vp, tbl, bias, kw, P) = _live_batch("f32", 0.0)
+    bs = kp.shape[2]
+    ppb = pages_per_block(bs, P)
+    pos = jnp.asarray([3, 16, 256, 1023, 1123, 0], jnp.int32)
+    n_pages = live_pages(pos, bs, P)
+    dead = [int(tbl[b, j]) for b in range(4)  # slots with their own pages
+            for j in range(-(-int(n_pages[b]) // ppb) * ppb, P)]
+    assert len(dead) == 3 * P - 16 - 16 - 32
+    kp, vp = (x.at[jnp.asarray(dead)].set(jnp.nan) for x in (kp, vp))
+    o_dead = paged_decode_attention(q, kp, vp, tbl, bias, n_pages=n_pages,
+                                    interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(o_dead), np.asarray(o))
+
+
+def test_pages_per_block_rule():
+    """Compute blocks: the largest divisor of P within 256 keys."""
+    from repro.kernels.decode_attention.kernel import pages_per_block
+
+    assert pages_per_block(16, 256) == 16  # starcoder2: 256 keys a block
+    assert pages_per_block(16, 64) == 16
+    assert pages_per_block(32, 2) == 2  # a short table is one block
+    assert pages_per_block(16, 24) == 12  # 16 does not divide 24
+    assert pages_per_block(16, 17) == 1
+    assert pages_per_block(512, 8) == 1  # a page wider than the budget
+
+
 # ------------------------------------------------------------------- rwkv6
 
 @pytest.mark.parametrize("B,H,S,hd,chunk", [

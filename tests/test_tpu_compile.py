@@ -66,16 +66,19 @@ N_PHYS = 2 + B * (L // BS)
 
 @pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8])
 def test_paged_decode_attention_compiles(one_chip, pool_dtype):
+    """The live-page walk: per-slot page counts, manual page DMAs."""
     S = _spec(one_chip)
     pool = S((N_PHYS, KV, BS, HD), pool_dtype)
     args = [S((B, H, HD), jnp.bfloat16), pool, pool,
-            S((B, L // BS), jnp.int32), S((B, L))]
+            S((B, L // BS), jnp.int32), S((B, L)), S((B,), jnp.int32)]
     if pool_dtype == jnp.int8:
         scale = S((N_PHYS, KV, BS, 1))
-        _compile(lambda q, k, v, t, b, ks, vs: paged_decode_attention_fwd(
-            q, k, v, t, b, k_scale=ks, v_scale=vs), *args, scale, scale)
+        _compile(lambda q, k, v, t, b, n, ks, vs: paged_decode_attention_fwd(
+            q, k, v, t, b, n_pages=n, k_scale=ks, v_scale=vs),
+            *args, scale, scale)
     else:
-        _compile(paged_decode_attention_fwd, *args)
+        _compile(lambda q, k, v, t, b, n: paged_decode_attention_fwd(
+            q, k, v, t, b, n_pages=n), *args)
 
 
 def test_decode_attention_compiles(one_chip):
