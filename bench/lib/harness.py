@@ -38,7 +38,8 @@ def load_module(path: pathlib.Path):
 
 def resolve(root: pathlib.Path, name: str) -> Dict:
     """The cell ``name`` with its configuration, traffic and the metrics it
-    reports, each loaded from its own file."""
+    reports, each loaded from its own file; and, where the configuration
+    names one, its reference module's path."""
     bench = load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -54,9 +55,15 @@ def resolve(root: pathlib.Path, name: str) -> Dict:
     layer = [m for m in bench["per_layer"]
              if name in m.get("workloads", [name] if m["moves"] in reported
                               else [])]
+    reference = None
+    if "reference" in conf:
+        reference = root / "bench" / "reference" / f"{conf['reference']}.py"
+        if not reference.is_file():
+            raise FileNotFoundError(f"no such reference: {reference}")
     return {"cell": cell, "config": conf, "traffic": traffic,
             "end_to_end": e2e, "per_layer": layer,
             "system": root / "bench" / "systems" / f"{conf['system']}.py",
+            "reference": reference,
             "readers": {m["name"]: root / "bench" / "metrics"
                         / f"{m['name']}.py" for m in layer}}
 
@@ -206,7 +213,8 @@ def make_context(root, args, cell, devs, peaks) -> SimpleNamespace:
         root=root, seed=int(args.seed), seconds=float(args.seconds),
         trace=bool(args.trace), control=bool(args.control),
         sweep=args.rate is not None, name=args.workload,
-        config=cell["config"], traffic=cell["traffic"], devices=devs,
+        config=cell["config"], traffic=cell["traffic"],
+        reference=cell["reference"], devices=devs,
         peaks=peaks, t_start=T_START, compiles=CompileCounter(),
         spans=Spans(), tracer=Tracer(root, args.workload),
         log=lambda msg: print(f"[bench {args.workload}] {msg}",

@@ -18,6 +18,22 @@ the program holds. Every matrix product runs at ``Precision.HIGHEST``
 product's inputs rounded to float8 e4m3 (per-row scales for activations,
 per-output-column scales for weights), the nearest step below the
 configuration's bfloat16.
+
+A replica configuration names its reference (``"reference":
+"dense_decoder"``), and ``bench/systems/replica.py`` loads it by path. Every
+reference module has the same names, and holds all that depends on the
+model's architecture:
+
+- ``WIDTHS``: the keys of the configuration's ``model`` section handed to
+  the program's config;
+- ``STRUCTURE``: the program-config fields, with their values, that the
+  layer equations here assume (a departure is refused before a run);
+- ``TINY``: the widths the CPU tests cut the model to;
+- ``program_params(seed, m, dtype)``: the program's parameter tree;
+- ``reference_logits(seed, m, seqs, wanted, *, precision, lengths,
+  n_out)``: float32 logits, ``precision="fp8"`` the control;
+- ``served_gaps(ref_logits, tokens)``: each served token's gap below the
+  reference's best.
 """
 
 from __future__ import annotations
@@ -38,6 +54,16 @@ TOP_LEAF_ID = {"embed": 0, "final_scale": 1, "final_bias": 2}
 LAYER_KEY_BASE = 16
 
 FP8_MAX = 448.0  # largest finite float8 e4m3fn
+
+WIDTHS = ("num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
+          "d_ff", "vocab_size", "window_size", "rope_theta", "norm_eps")
+STRUCTURE = {"mlp_type": "gelu", "norm_type": "layernorm", "use_bias": True,
+             "tie_embeddings": True, "pos_type": "rope", "attn_softcap": 0.0,
+             "final_softcap": 0.0, "post_norm": False, "embed_scale": False,
+             "prefix_len": 0, "norm_plus_one": False,
+             "attn_pattern": ("local",)}
+TINY = dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
+            head_dim=16, d_ff=256, vocab_size=128, window_size=64)
 
 
 def base_key(seed: int):

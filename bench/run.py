@@ -6,7 +6,8 @@
 Everything a cell is made of is found by name: the cell in
 ``BENCHMARK.json``, its configuration in ``bench/configs/<config>.json``
 (whose ``system`` names the code that runs it,
-``bench/systems/<system>.py``), its traffic in
+``bench/systems/<system>.py``, and whose ``reference``, where it has one,
+names the plain reference, ``bench/reference/<reference>.py``), its traffic in
 ``bench/traffic/<traffic>.json``, each per-layer metric's reader in
 ``bench/metrics/<metric>.py`` and each kernel's work function in
 ``bench/kernels/<kernel>.py``.

@@ -6,6 +6,12 @@ The harness holds the arrival queue and hands a request to the engine when a
 slot is free (``submit`` + ``admit``, under the ``bench.admit`` span); then
 one ``step`` decodes a token for every active slot (``bench.step``). Every
 request is timed from when it was due, not from when it was handed over.
+
+All that depends on the model's architecture (the widths handed to the
+program, the structure it must have, the weights and the float32 reference)
+comes from the reference module that the configuration names
+(``bench/reference/<reference>.py``, loaded by path; its docstring lists
+the names every reference has).
 """
 
 from __future__ import annotations
@@ -20,33 +26,24 @@ import numpy as np
 
 from bench.lib import harness, traffic
 from bench.lib import trace as tr
-from bench.reference import dense_decoder as ref
 
 #: requests due in the window are followed this long past its close, for
 #: their first token
 DRAIN_S = 60.0
 
 
-def program_config(conf: Dict):
+def program_config(conf: Dict, ref):
     """The program's model configuration, checked against the file: the
-    widths come from the file; the structure the reference implements has
-    to be the program's."""
+    widths (``ref.WIDTHS``) come from the file; the structure the reference
+    implements (``ref.STRUCTURE``) has to be the program's."""
     from repro.configs import get_config
 
     m = conf["model"]
-    widths = {k: m[k] for k in ("num_layers", "d_model", "num_heads",
-                                "num_kv_heads", "head_dim", "d_ff",
-                                "vocab_size", "window_size", "rope_theta",
-                                "norm_eps")}
     cfg = get_config(conf["program"]["config"]).replace(
-        **widths, dtype=m["dtype"], param_dtype=m["dtype"],
+        **{k: m[k] for k in ref.WIDTHS}, dtype=m["dtype"],
+        param_dtype=m["dtype"],
         use_pallas=bool(conf["program"]["use_pallas"]))
-    want = {"mlp_type": "gelu", "norm_type": "layernorm", "use_bias": True,
-            "tie_embeddings": True, "pos_type": "rope", "attn_softcap": 0.0,
-            "final_softcap": 0.0, "post_norm": False, "embed_scale": False,
-            "prefix_len": 0, "norm_plus_one": False,
-            "attn_pattern": ("local",)}
-    bad = {k: getattr(cfg, k) for k, v in want.items()
+    bad = {k: getattr(cfg, k) for k, v in ref.STRUCTURE.items()
            if getattr(cfg, k) != v}
     if bad:
         raise ValueError(f"program config departs from the reference's "
@@ -65,7 +62,7 @@ class Served:
         self.t_tokens: List[float] = []
 
 
-def build(ctx):
+def build(ctx, ref):
     """Weights, engine and requests; nothing compiled yet."""
     import jax
 
@@ -73,7 +70,7 @@ def build(ctx):
     from repro.runtime.batching import ContinuousBatcher
 
     conf, m, eng = ctx.config, ctx.config["model"], ctx.config["engine"]
-    cfg = program_config(conf)
+    cfg = program_config(conf, ref)
     model = build_model(cfg)
     params = jax.block_until_ready(
         ref.program_params(ctx.seed, m, m["dtype"]))
@@ -117,7 +114,8 @@ def step(bk, clock) -> None:
 def run(ctx) -> Dict:
     import jax
 
-    model, params, bk, reqs = build(ctx)
+    ref = harness.load_module(ctx.reference)
+    model, params, bk, reqs = build(ctx, ref)
     spans = ctx.spans
     items = [Served(r, None) for r in reqs]
     in_flight = [it for it in items if it.req.due_s < 0]
@@ -239,7 +237,7 @@ def run(ctx) -> Dict:
     # free the program's state before the reference runs
     del model, params, bk
     gc.collect()
-    checks, extra = check(ctx, finished)
+    checks, extra = check(ctx, ref, finished)
     extra.update(window_compiles=window_compiles,
                  window_prefill_programs=window_prefill_jits)
     if ctx.sweep:
@@ -281,7 +279,7 @@ def sample(items: List[Served], seed: int, want_tokens: int, most: int
     return out
 
 
-def check(ctx, finished: List[Served]):
+def check(ctx, ref, finished: List[Served]):
     """Every served token of a sample of finished requests against the
     float32 reference's logits at the same position: the widest gap by
     which a served token's logit lies below the reference's best. With

@@ -8,9 +8,8 @@ import pytest
 
 from bench.reference import dense_decoder as ref
 from bench.reference import fleet as fref
-from bench.tests.tiny_cells import TINY_MODEL
 
-M = dict(TINY_MODEL, rope_theta=1e5, norm_eps=1e-5, dtype="bfloat16")
+M = dict(ref.TINY, rope_theta=1e5, norm_eps=1e-5, dtype="bfloat16")
 
 
 def _program(max_slots=2, max_len=64):
@@ -19,7 +18,7 @@ def _program(max_slots=2, max_len=64):
     from repro.runtime.batching import ContinuousBatcher
 
     cfg = get_config("starcoder2-3b").replace(
-        **{k: M[k] for k in TINY_MODEL}, use_pallas=True)
+        **{k: M[k] for k in ref.TINY}, use_pallas=True)
     model = build_model(cfg)
     params = ref.program_params(11, M, "bfloat16")
     return model, params, ContinuousBatcher(

@@ -1,6 +1,7 @@
 """The benchmark's cells cut to CPU test size: the same files, systems and
-reference, with the widths, the fleet and the traffic made tiny, run
-without the harness's look for a chip."""
+reference, with the widths (a replica's from its reference's ``TINY``), the
+fleet and the traffic made tiny, run without the harness's look for a
+chip."""
 
 from __future__ import annotations
 
@@ -11,18 +12,16 @@ from types import SimpleNamespace
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 
-TINY_MODEL = dict(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
-                  head_dim=16, d_ff=256, vocab_size=128, window_size=64)
 FAKE_PEAKS = {"flops_per_s": 1e12, "hbm_bytes_per_s": 1e11, "hbm_bytes": 1e9}
 
 
-def tiny_cell(name: str):
+def tiny_cell(name: str, root: pathlib.Path = ROOT):
     from bench.lib import harness
 
-    cell = copy.deepcopy(harness.resolve(ROOT, name))
+    cell = copy.deepcopy(harness.resolve(root, name))
     conf, t = cell["config"], cell["traffic"]
     if conf["system"] == "replica":
-        conf["model"].update(TINY_MODEL)
+        conf["model"].update(harness.load_module(cell["reference"]).TINY)
         conf["engine"].update(max_slots=4, max_len=64)
         conf["limits"].update(max_logit_gap=0.05, min_sampled_tokens=16)
         t["prompt"].update(median=20, min=4, max=40)
@@ -47,7 +46,7 @@ def tiny_cell(name: str):
 
 
 def run_tiny(name: str, seed: int, seconds: float, *, control=False,
-             alter=None, patch=None):
+             alter=None, patch=None, root: pathlib.Path = ROOT):
     """One run of a tiny cell on the CPU; returns (result line, output)."""
     import io
     import json
@@ -57,10 +56,10 @@ def run_tiny(name: str, seed: int, seconds: float, *, control=False,
 
     from bench.lib import harness
 
-    cell = tiny_cell(name)
+    cell = tiny_cell(name, root)
     args = SimpleNamespace(seed=seed, seconds=seconds, trace=0,
                            control=int(control), rate=None, workload=name)
-    ctx = harness.make_context(ROOT, args, cell, jax.devices()[:1],
+    ctx = harness.make_context(root, args, cell, jax.devices()[:1],
                                FAKE_PEAKS)
     system = harness.load_module(cell["system"])
     if patch is not None:
